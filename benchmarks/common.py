@@ -11,6 +11,23 @@ import time
 from typing import Callable
 
 
+def use_compile_cache(checkout: str) -> str:
+    """Point JAX's persistent compilation cache at ``<checkout>/.jax_cache``
+    and return the directory in use.  Where ``JAX_COMPILATION_CACHE_DIR``
+    is set, JAX reads it itself and nothing is set here.  Entry points
+    (`benchmarks.run`, `chip_smoke.py`) call this before their first
+    compile; the library never does, since tests compile for described
+    chips whose executables cannot be read back from a cache."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(os.path.abspath(checkout), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def timed(fn: Callable, repeats: int = 1):
     """Wall-clock `fn`, synchronizing device outputs before reading the
     clock: JAX dispatches asynchronously, so without blocking on the result

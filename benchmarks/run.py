@@ -212,6 +212,8 @@ def write_report(figures: dict, path: str, obs: dict = None) -> None:
 
 
 def main() -> None:  # reprolint: allow[naked-clock] -- times whole bench modules (imports + device work each bench already blocks on), not individual device calls; common.timed is for those
+    common.use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     print("name,us_per_call,derived")
     failures = 0
     only = sys.argv[1:] or None

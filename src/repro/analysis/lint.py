@@ -53,7 +53,8 @@ DEFAULT_SCOPE: Dict[str, Tuple[Tuple[str, ...], Tuple[str, ...]]] = {
     # sit inside `timed` or carry the one documented recorder-internal
     # pragma (host-sync already covers obs via the "*" include above)
     "naked-clock": (("benchmarks/*.py", "src/repro/obs/*.py"), ()),
-    # the two files that OWN the version guards are the only exceptions --
+    # the two files that own the one call of each API are the only
+    # exceptions --
     # blockwise.py stays in scope: it reaches shard_map strictly through
     # the compat shim (`from .compat import shard_map`)
     "compat-shim": (("*",),

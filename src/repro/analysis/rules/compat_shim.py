@@ -1,12 +1,10 @@
-"""compat-shim: JAX-version-dependent APIs route through the shims.
+"""compat-shim: shard_map and mesh axis types route through one module each.
 
-The toolchain pins JAX 0.4.37, where ``shard_map`` lives in
-``jax.experimental.shard_map`` (kwarg ``check_rep``) while newer JAX
-exposes ``jax.shard_map`` (kwarg ``check_vma``), and where
-``jax.sharding.AxisType`` / ``jax.make_mesh(axis_types=...)`` may or may
-not exist.  ``repro/parallel/compat.py`` and ``repro/launch/mesh.py`` own
-those guards; every other call site must import from the shims, or the
-next JAX bump breaks call sites one by one instead of in one file.
+``repro/parallel/compat.py`` owns the one ``jax.shard_map`` call (with its
+``check_vma`` setting) and ``repro/launch/mesh.py`` the one
+``jax.make_mesh(axis_types=...)`` call; every other call site imports
+from them, so a JAX upgrade that renames either API changes one file
+instead of breaking call sites one by one.
 
 Flags, outside the two shim files (excluded via the rule's scope config):
 
@@ -24,9 +22,9 @@ from typing import List
 from ..report import Finding
 from .base import FileContext, Rule
 
-_MSG = ("version-dependent JAX API used directly; route through "
-        "repro.parallel.compat / repro.launch.mesh so the 0.4.x/0.5.x "
-        "renames stay guarded in one place")
+_MSG = ("shard_map / AxisType used directly; route through "
+        "repro.parallel.compat / repro.launch.mesh so each API is called "
+        "from one place")
 
 
 def _flagged_import(node: ast.ImportFrom) -> bool:
@@ -48,7 +46,7 @@ def _flagged_import(node: ast.ImportFrom) -> bool:
 class CompatShimRule(Rule):
     id = "compat-shim"
     description = ("shard_map/AxisType only via parallel/compat.py and "
-                   "launch/mesh.py (JAX 0.4.x/0.5.x rename guards)")
+                   "launch/mesh.py (one call site per API)")
 
     def check(self, ctx: FileContext) -> List[Finding]:
         out: List[Finding] = []

@@ -229,15 +229,12 @@ def _bfs_device_fn(g: Graph, want_next_hop: bool):
     seeds each discovered node with its own id), which is the same set-min
     the host engine computes via its segmented sort -- the discovering
     edges of w are exactly the frontier neighbors of w on an undirected
-    graph -- so outputs are bit-identical.  Returns None (callers fall
-    back to the host loop) when jax is unavailable or the graph has no
-    edges.
+    graph -- so outputs are bit-identical.  Returns None (callers take the
+    host loop) when the graph has no edges.
     """
-    try:
-        import jax
-        import jax.numpy as jnp
-    except Exception:  # pragma: no cover - jax is a hard dep in this repo
-        return None
+    import jax
+    import jax.numpy as jnp
+
     nb, _ = g.padded_neighbors
     n, dmax = nb.shape
     if dmax == 0:
@@ -418,7 +415,7 @@ def _next_hop_columns(nb: np.ndarray, dests: np.ndarray,
 def _dest_device_fn(g: Graph):
     """Device twin of one `destination_blocks` block for the sharded
     backend: the no-next-hop BFS plus the `_next_hop_rows` column
-    derivation, both traced.  None when the host fallback applies."""
+    derivation, both traced.  None on an edge-free graph (host loop)."""
     bfs = _bfs_device_fn(g, False)
     if bfs is None:
         return None

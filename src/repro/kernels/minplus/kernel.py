@@ -9,6 +9,15 @@ k-dimension innermost in the grid for accumulation.
 
 Block shapes default to (128, 128, 128): 3 f32 tiles = 192 KiB << 16 MiB
 VMEM, and 128 lanes align with the VPU (8, 128) vregs.
+
+The fluid solver's per-candidate path costs (`ops.path_costs`) have no
+kernel here.  They are a data-dependent gather from a per-link delay table
+of E + 1 entries (505,601 at PF(79)), and the TPU compiler (Mosaic) only
+lowers gathers whose table, indices and output share one tile shape: a
+1-D table with a [bf, K, L] index tile is refused with "Only 2D gather is
+supported", a [E / 128, 128] table with per-element row indices with
+"Shape mismatch in input, indices and output".  XLA's own gather is the
+one path on every backend.
 """
 
 from __future__ import annotations
@@ -31,49 +40,6 @@ def _minplus_kernel(a_ref, b_ref, o_ref):
     # [bm, bk, 1] + [1, bk, bn] -> min over k
     cand = jnp.min(a[:, :, None] + b[None, :, :], axis=1)
     o_ref[...] = jnp.minimum(o_ref[...], cand)
-
-
-def _path_cost_kernel(delay_ref, eidx_ref, o_ref):
-    """Grid (i,) over flow tiles.  o[f, k] = sum_l delay[eidx[f, k, l]].
-
-    The delay table rides whole in VMEM (one row of ``[1, Ep]``; even the
-    PF(79) scale tier is ~500k links = 2 MB fp32 << 16 MiB), while the
-    ``[bf, K, L]`` edge-id tile streams per grid step -- the same
-    stay-resident / stream split as the tropical matmul above, with the
-    gather standing in for the A-row stream."""
-    d = delay_ref[0, :]          # [Ep]
-    idx = eidx_ref[...]          # [bf, K, L]
-    o_ref[...] = jnp.take(d, idx, axis=0).sum(axis=-1)
-
-
-@functools.partial(jax.jit, static_argnames=("bf", "interpret"))
-def path_costs_pallas(delay: jnp.ndarray, eidx: jnp.ndarray, bf: int = 256,
-                      interpret: bool = True):
-    """Tiled per-candidate path-cost reduction; see `ref.path_costs_ref`.
-
-    ``delay``: [E + 1] padded per-link delay table (pad slot must be 0).
-    ``eidx``: [F, K, L] int32 edge ids with pads remapped to E.
-    Returns [F, K] costs in ``delay.dtype``.
-    """
-    f, k, l = eidx.shape
-    ep = delay.shape[0]
-    fp_ = -(-max(f, 1) // bf) * bf
-    # pad rows gather only the zero pad slot, so their cost is 0 and the
-    # trailing rows are simply dropped below
-    eidx = jnp.pad(eidx, ((0, fp_ - f), (0, 0), (0, 0)),
-                   constant_values=ep - 1)
-    out = pl.pallas_call(
-        _path_cost_kernel,
-        grid=(fp_ // bf,),
-        in_specs=[
-            pl.BlockSpec((1, ep), lambda i: (0, 0)),
-            pl.BlockSpec((bf, k, l), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bf, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((fp_, k), delay.dtype),
-        interpret=interpret,
-    )(delay[None, :], eidx)
-    return out[:f]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))

@@ -1,44 +1,34 @@
-"""Public ops for tropical matmul / APSP with automatic backend choice."""
+"""Public ops for tropical matmul / APSP and the fluid solver's path costs."""
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...obs.profiler import named_scope
-from .kernel import minplus_pallas, path_costs_pallas
-from .ref import adjacency_to_dist0, minplus_ref, path_costs_ref, INF
+from .kernel import minplus_pallas
+from .ref import adjacency_to_dist0, minplus_ref, INF
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def path_costs(delay: jnp.ndarray, eidx: jnp.ndarray,
-               use_pallas: bool = None, block: int = 256) -> jnp.ndarray:
+def path_costs(delay: jnp.ndarray, eidx: jnp.ndarray) -> jnp.ndarray:
     """[F, K] per-candidate path costs: ``sum_l delay[eidx[f, k, l]]``.
 
     The fluid solver's per-iteration best-response reduction (tropical:
-    sum over links here, min over candidates in the caller).  Backend
-    choice follows the repo's two-engine discipline: ``use_pallas=None``
-    (the default) picks the tiled Pallas kernel on TPU and the
-    bit-identical jnp reference everywhere else -- interpret-mode Pallas
-    is Python-speed on CPU, and this runs inside every Frank-Wolfe step.
-    Traceable under jit/vmap either way (the backend choice is static).
+    sum over links here, min over candidates in the caller).  One XLA
+    gather-and-sum on every backend: the TPU compiler cannot lower this
+    gather as a Pallas kernel (see `kernel.py`).  `ref.path_costs_ref` is
+    the plain per-hop reference it is checked against.  Traceable under
+    jit/vmap.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     # label the reduction in XLA profiles: this op runs inside every
     # Frank-Wolfe step, and the scope name makes it findable in a
-    # jax.profiler capture (no-op shim when the profiler is unavailable)
-    with named_scope("minplus.path_costs"):
-        if use_pallas:
-            return path_costs_pallas(delay, eidx, bf=block,
-                                     interpret=not _on_tpu())
-        return path_costs_ref(delay, eidx)
+    # jax.profiler capture
+    with jax.named_scope("minplus.path_costs"):
+        return delay[eidx].sum(axis=-1)
 
 
 def minplus(a: jnp.ndarray, b: jnp.ndarray, use_pallas: bool = True,

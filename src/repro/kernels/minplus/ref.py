@@ -20,10 +20,17 @@ def path_costs_ref(delay: jnp.ndarray, eidx: jnp.ndarray) -> jnp.ndarray:
     ``cost[f, k] = sum_l delay[eidx[f, k, l]]`` -- the (+)-half of the
     tropical best-response reduction the fluid solver runs per
     Frank-Wolfe iteration (the min-over-K half stays in the caller, which
-    also needs the full ``[F, K]`` cost for the duality gap).  This jnp
-    form is the bit-identical CPU twin of ``path_costs_pallas``.
+    also needs the full ``[F, K]`` cost for the duality gap).
+
+    The plain reference for `ops.path_costs`: one hop at a time, added
+    left to right.  `path_costs` gathers the whole ``[F, K, L]`` block and
+    reduces it, so a backend may sum the L terms in another order; the
+    two agree to float32 rounding of an L-term sum of positive delays.
     """
-    return delay[eidx].sum(axis=-1)
+    cost = delay[eidx[..., 0]]
+    for hop in range(1, eidx.shape[-1]):
+        cost = cost + delay[eidx[..., hop]]
+    return cost
 
 
 def adjacency_to_dist0(adj: jnp.ndarray) -> jnp.ndarray:

@@ -187,8 +187,6 @@ def _f32_upcast_bytes(txt: str, floor: int = 64 << 20) -> float:
 def _analyze(out: dict, compiled, cfg, plan, shape_name: str, n_dev: int) -> dict:
     sh = SHAPES[shape_name]
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # JAX <= 0.4.x: one dict per partition
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     mem = {}
     if ma is not None:

@@ -1,11 +1,6 @@
 """Production meshes.  Functions only -- importing this module never touches
 jax device state (required: the dry-run sets XLA_FLAGS before first init).
-
-JAX-version constraint: `jax.sharding.AxisType` (and `jax.make_mesh`'s
-`axis_types=` keyword) only exist on newer JAX; the pinned toolchain runs
-JAX 0.4.37, which has neither.  `make_mesh` below passes `axis_types` only
-when available -- explicit-Auto and the old implicit default are equivalent
-for every mesh we build.  Use it instead of calling `jax.make_mesh` directly.
+Use `make_mesh` below instead of calling `jax.make_mesh` directly.
 """
 
 from __future__ import annotations
@@ -16,16 +11,10 @@ __all__ = ["make_mesh", "make_production_mesh", "make_test_mesh"]
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """`jax.make_mesh` with Auto axis types when this JAX supports them."""
+    """`jax.make_mesh` with every axis of type Auto."""
     kwargs = {"devices": devices} if devices is not None else {}
-    if hasattr(jax.sharding, "AxisType"):
-        try:
-            return jax.make_mesh(shape, axes, **kwargs,
-                                 axis_types=(jax.sharding.AxisType.Auto,)
-                                 * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(shape, axes, **kwargs,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -2,12 +2,10 @@
 
 Spans/counters/gauges with explicit device-sync boundaries, a Recorder
 emitting Chrome-trace-event JSONL (Perfetto-loadable via ``python -m
-repro.obs.report --to-chrome``), convergence traces from the fluid
-solver, and guarded jax.profiler annotations.  Dependency-free: jax is
-only touched lazily at sync/annotation points.
+repro.obs.report --to-chrome``), and convergence traces from the fluid
+solver.  Dependency-free: jax is only touched lazily at sync points.
 """
 
-from .profiler import named_scope, trace_annotation
 from .record import (
     NullRecorder,
     Recorder,
@@ -24,8 +22,6 @@ __all__ = [
     "Recorder",
     "Span",
     "get_recorder",
-    "named_scope",
     "recording",
     "set_recorder",
-    "trace_annotation",
 ]

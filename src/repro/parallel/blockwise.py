@@ -50,8 +50,7 @@ from typing import Callable, Iterator, Optional, Sequence, Tuple
 
 import numpy as np
 
-# repro.obs is stdlib-only, so these keep the no-jax-at-import property
-from ..obs.profiler import trace_annotation
+# repro.obs is stdlib-only, so this keeps the no-jax-at-import property
 from ..obs.record import get_recorder
 
 __all__ = [
@@ -145,13 +144,10 @@ def plan_blocks(total: int, per_item_bytes: Optional[int] = None,
 
 
 def available_devices() -> int:
-    """Visible jax device count; 1 when jax is unavailable.  On CPU the
-    count follows ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
-    try:
-        import jax
-        return len(jax.devices())
-    except Exception:  # jax missing or uninitializable: host loop only
-        return 1
+    """Visible jax device count.  On CPU the count follows
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
+    import jax
+    return len(jax.devices())
 
 
 def _as_tuple(out) -> tuple:
@@ -176,11 +172,11 @@ def _resolve_backend(backend: str, plan: BlockPlan, device_fn) -> str:
 
 
 def _run_host(items: np.ndarray, plan: BlockPlan,
-              host_fn: Callable) -> Iterator[Tuple[np.ndarray, tuple]]:
+              host_fn: Callable) -> Iterator[Tuple[np.ndarray, tuple, None]]:
     for i in range(plan.num_blocks):
         lo, hi = plan.bounds(i)
         blk = items[lo:hi]
-        yield blk, _as_tuple(host_fn(blk))
+        yield blk, _as_tuple(host_fn(blk)), None
 
 
 # `jax.jit` keys its trace cache on the wrapped callable's identity, and
@@ -193,13 +189,15 @@ def _run_host(items: np.ndarray, plan: BlockPlan,
 # jax.jit already keys on under the one cached wrapper.  Callers only
 # benefit when they pass a stable `device_fn` object (a module-level
 # function or a retained closure); a lambda rebuilt per call misses.
-_MAPPED_CACHE: "OrderedDict[tuple, Callable]" = OrderedDict()
+_MAPPED_CACHE: "OrderedDict[tuple, tuple]" = OrderedDict()
 _MAPPED_CACHE_SIZE = 16
 
 
-def _mapped_fn(device_fn: Callable, devices: tuple) -> Callable:
+def _mapped_fn(device_fn: Callable, devices: tuple) -> tuple:
+    """(jitted shard_map of `device_fn`, the input sharding that puts
+    block row j on ``devices[j]``), from the cross-call cache."""
     import jax
-    from jax.sharding import Mesh, PartitionSpec
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     from .compat import shard_map
 
@@ -223,22 +221,24 @@ def _mapped_fn(device_fn: Callable, devices: tuple) -> Callable:
 
     mapped = jax.jit(shard_map(_per_device, mesh=mesh, in_specs=spec,
                                out_specs=spec))
-    _MAPPED_CACHE[key] = mapped
+    _MAPPED_CACHE[key] = (mapped, NamedSharding(mesh, spec))
     while len(_MAPPED_CACHE) > _MAPPED_CACHE_SIZE:
         _MAPPED_CACHE.popitem(last=False)
-    return mapped
+    return _MAPPED_CACHE[key]
 
 
 def _run_sharded(items: np.ndarray, plan: BlockPlan,
-                 device_fn: Callable) -> Iterator[Tuple[np.ndarray, tuple]]:
+                 device_fn: Callable) -> Iterator[Tuple[np.ndarray, tuple,
+                                                        int]]:
     """One block per device per round; the mapped function comes from the
     cross-call `_MAPPED_CACHE` and block shapes are padded to a constant
-    [devices, block], so a stable `device_fn` compiles exactly once."""
+    [devices, block], so a stable `device_fn` compiles exactly once.
+    Each round's stacked blocks are placed row j on device j, and each
+    yielded block carries the id of the device whose output shard it is."""
     import jax
-    import jax.numpy as jnp
 
     ndev = max(1, min(plan.devices, len(jax.devices())))
-    mapped = _mapped_fn(device_fn, tuple(jax.devices()[:ndev]))
+    mapped, sharding = _mapped_fn(device_fn, tuple(jax.devices()[:ndev]))
 
     for r in range(plan.num_rounds):
         first = r * ndev
@@ -250,12 +250,14 @@ def _run_sharded(items: np.ndarray, plan: BlockPlan,
                 blk = np.concatenate(
                     [blk, np.repeat(blk[-1:], plan.block - len(blk))])
             blocks.append(blk)
-        with trace_annotation("blockwise.round"):
-            outs = mapped(jnp.asarray(np.stack(blocks)))
+        with jax.profiler.TraceAnnotation("blockwise.round"):
+            outs = mapped(jax.device_put(np.stack(blocks), sharding))
+            owner = {s.index[0].start or 0: s.device.id
+                     for s in outs[0].addressable_shards}
             outs = tuple(np.asarray(o) for o in outs)  # one host sync per round
         for j in range(min(ndev, plan.num_blocks - first)):
             lo, hi = plan.bounds(first + j)
-            yield items[lo:hi], tuple(o[j, :hi - lo] for o in outs)
+            yield items[lo:hi], tuple(o[j, :hi - lo] for o in outs), owner[j]
 
 
 def run_blocks(items: Sequence, plan: BlockPlan, host_fn: Callable,
@@ -281,8 +283,9 @@ def run_blocks(items: Sequence, plan: BlockPlan, host_fn: Callable,
     loop, so single-device environments always take the reference path.
 
     Every block is wrapped in a ``blockwise.block`` obs span recording
-    the resolved backend, block index, item count, and (when the plan
-    carries `per_item_bytes`) the block's working-set bytes.  The
+    the resolved backend, block index, item count, (when the plan
+    carries `per_item_bytes`) the block's working-set bytes, and (sharded
+    backend) the id of the device that computed it.  The
     sharded backend computes a whole round of `devices` blocks at its
     first block's ``next()``, so that round's wall time lands on the
     round's first span -- per-round attribution, not per-block.
@@ -302,8 +305,10 @@ def run_blocks(items: Sequence, plan: BlockPlan, host_fn: Callable,
     nblocks = plan.num_blocks
     for i in range(nblocks):
         with rec.span("blockwise.block", backend=resolved, index=i) as sp:
-            blk, outs = next(inner)
+            blk, outs, device = next(inner)
             sp.set(items=len(blk))
+            if device is not None:
+                sp.set(device=device)
             if plan.per_item_bytes:
                 sp.set(bytes=peak_bytes(len(blk), plan.per_item_bytes))
         if progress is not None:

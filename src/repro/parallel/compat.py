@@ -1,10 +1,8 @@
-"""JAX version compatibility for parallelism primitives.
+"""The one entry point for `shard_map` in this repo.
 
-The pinned toolchain runs JAX 0.4.37, where `shard_map` still lives in
-`jax.experimental.shard_map` and the replication-check kwarg is named
-`check_rep`; newer JAX exposes `jax.shard_map` with `check_vma`.  Routing
-through this module keeps call sites version-agnostic.  See also
-`repro.launch.mesh.make_mesh` for the matching `AxisType` guard.
+Call sites import `shard_map` from here, never from jax directly (the
+reprolint `compat-shim` rule enforces it), so the replication-check
+setting lives in one place.
 """
 
 from __future__ import annotations
@@ -15,14 +13,6 @@ __all__ = ["shard_map"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` with replication checking off, on any supported JAX."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:  # jax.shard_map predates the check_vma rename
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """`jax.shard_map` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -78,8 +78,8 @@ util_lb > 1, and `_certified_saturation` uses those decisions to
 early-exit each in-jit warm-started probe (lax.while_loop over strided
 step chunks) instead of running a fixed budget.  The per-iteration
 best-response cost reduction is routed through
-`kernels.minplus.path_costs` -- the tiled Pallas kernel on TPU, its
-bit-identical jnp twin on CPU.  Tight brackets need small gaps, and the
+`kernels.minplus.path_costs` (one XLA gather on every backend).  Tight
+brackets need small gaps, and the
 fp32 gap has an inner-product-cancellation noise floor (~1e-3 * total
 demand): set JAX_ENABLE_X64=1 and the certified engine picks float64
 automatically (tighter default util_tol) while the uncertified engines
@@ -352,8 +352,7 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
                         oblivious modes (their split is the fixed point).
       loads(split, demand) -> rho [E]
       cost_of(rho)      -> per-candidate path cost [F, K], routed through
-                        `kernels.minplus.path_costs` (tiled Pallas kernel
-                        on TPU, bit-identical jnp twin on CPU).
+                        `kernels.minplus.path_costs`.
       fw_target(split, rho) -> [F, K] Frank-Wolfe best-response target
                         (adaptive modes only; includes the UGAL_PF gate),
                         shared by `equilibrate` and the truncation-error
@@ -384,8 +383,10 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     scatter-add for pathologically skewed incidence counts.  The
     optimization barriers keep XLA from fusing the weight / delay tables
     into their consuming gathers, which would serialize them; `barrier=False`
-    drops them (JAX 0.4.37 has no vmap batching rule for
-    `optimization_barrier`, so the vmapped batch solver cannot use them).
+    drops them.  The vmapped batch solvers pass `barrier=False`.  JAX can
+    batch `optimization_barrier`, so this is a choice, not a limit: the
+    batch tests pin the barrier-free program's results, and no TPU
+    measurement yet says the barriers help there.
 
     `cert_equilibrate(split0, demand, max_iters, util_tol, t0=0.0,
     decide_at=None, trace_cap=0)` returns `(split, rho, gap, mu_lb,

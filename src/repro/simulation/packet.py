@@ -411,6 +411,8 @@ class PacketResult:
     cycles: int
     size: int
     capacity: int
+    admitted: int           # packets that entered the network (conservation:
+    #   delivered + dropped + occ_sum[-1] == admitted <= P)
 
     def latencies(self) -> np.ndarray:
         """Sorted int32 latency multiset of delivered packets."""
@@ -610,7 +612,8 @@ def simulate_packets_reference(wl: PacketWorkload,
     return PacketResult(deliver_t=deliver_t, delivered=delivered,
                         dropped=dropped, inject_t=wl.pkt_t.copy(),
                         occ_sum=occ_sum, occ_max=occ_max, occ_rec=occ_rec,
-                        cycles=wl.cycles, size=size, capacity=q_cap)
+                        cycles=wl.cycles, size=size, capacity=q_cap,
+                        admitted=admitted)
 
 
 def _decide_reference(wl: PacketWorkload, occ0: List[int], ep: int, f: int,
@@ -826,8 +829,9 @@ def _run_batched(eidx, hops, n_valid, pkt_flow, pkt_t, pkt_cand, src_off,
     else:
         dropped = jnp.zeros(p_pad + 1, bool)
         ys = ys0
-    _, _, _, _, _, _, _, dlv_t, dlv = state
-    return dlv_t[:-1], dlv[:-1], dropped[:-1], ys
+    _, _, _, _, _, _, ptr, dlv_t, dlv = state
+    admitted = (ptr - src_off[:-1]).sum()
+    return dlv_t[:-1], dlv[:-1], dropped[:-1], admitted, ys
 
 
 def simulate_packets(wl: PacketWorkload,
@@ -849,9 +853,10 @@ def simulate_packets(wl: PacketWorkload,
             dropped=np.zeros(0, bool), inject_t=np.zeros(0, np.int32),
             occ_sum=z, occ_max=z.copy(),
             occ_rec=np.zeros((wl.cycles, len(rec)), np.int32),
-            cycles=wl.cycles, size=wl.size, capacity=wl.capacity)
+            cycles=wl.cycles, size=wl.size, capacity=wl.capacity,
+            admitted=0)
     seg0 = min(wl.switch_cycle, wl.cycles)
-    dlv_t, dlv, dropped, ys = _run_batched(
+    dlv_t, dlv, dropped, admitted, ys = _run_batched(
         *_arrays(wl, rec), e_num=wl.num_links, size=wl.size,
         capacity=wl.capacity, adaptive=wl.adaptive, gated=wl.gated,
         seg0=seg0, seg1=wl.cycles - seg0)
@@ -862,7 +867,8 @@ def simulate_packets(wl: PacketWorkload,
         occ_max=np.asarray(ys[1], dtype=np.int32),
         occ_rec=np.asarray(ys[2], dtype=np.int32).reshape(wl.cycles,
                                                           len(rec)),
-        cycles=wl.cycles, size=wl.size, capacity=wl.capacity)
+        cycles=wl.cycles, size=wl.size, capacity=wl.capacity,
+        admitted=int(admitted))
 
 
 def simulate_packets_batch(wls: Sequence[PacketWorkload]
@@ -889,7 +895,7 @@ def simulate_packets_batch(wls: Sequence[PacketWorkload]
         capacity=w0.capacity, adaptive=w0.adaptive, gated=w0.gated,
         seg0=min(w0.switch_cycle, w0.cycles),
         seg1=w0.cycles - min(w0.switch_cycle, w0.cycles))
-    dlv_t, dlv, dropped, ys = jax.vmap(run)(*stacks)
+    dlv_t, dlv, dropped, admitted, ys = jax.vmap(run)(*stacks)
     out = []
     for i, w in enumerate(wls):
         out.append(PacketResult(
@@ -898,5 +904,6 @@ def simulate_packets_batch(wls: Sequence[PacketWorkload]
             occ_sum=np.asarray(ys[0][i], dtype=np.int32),
             occ_max=np.asarray(ys[1][i], dtype=np.int32),
             occ_rec=np.zeros((w.cycles, 0), np.int32),
-            cycles=w.cycles, size=w.size, capacity=w.capacity))
+            cycles=w.cycles, size=w.size, capacity=w.capacity,
+            admitted=int(admitted[i])))
     return out

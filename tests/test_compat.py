@@ -1,7 +1,5 @@
-"""repro.parallel.compat must import and actually shard a computation on
-the pinned JAX (0.4.x at container build time, but the shim is the one
-place allowed to branch on version, so exercise whichever branch is
-live)."""
+"""repro.parallel.compat must import and actually shard a computation:
+it holds the repo's one `jax.shard_map` call."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,7 +17,7 @@ def test_shard_map_shim_runs():
 
 
 def test_shim_is_the_only_shard_map_entry():
-    # the shim exports exactly the guarded symbol; call sites import this,
+    # the module exports exactly shard_map; call sites import this,
     # never jax.experimental directly (enforced by reprolint compat-shim)
     import repro.parallel.compat as compat
     assert compat.__all__ == ["shard_map"]

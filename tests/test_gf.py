@@ -1,7 +1,7 @@
 """Field axioms of GF(q) for primes and prime powers (hypothesis)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.gf import GF, is_prime_power, primes_and_prime_powers
 

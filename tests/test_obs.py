@@ -267,6 +267,9 @@ spans = [e for e in rec.events()
          if e["ph"] == "X" and e["name"] == "blockwise.block"]
 assert len(spans) == plan.num_blocks, (len(spans), plan.num_blocks)
 assert all(s["args"]["backend"] == "sharded" for s in spans)
+# one round: block j was computed on device j, not all on device 0
+assert [s["args"]["device"] for s in spans] == \
+    [d.id for d in jax.devices()[:plan.num_blocks]]
 retraces = [e for e in rec.events() if e["name"] == "blockwise.retrace"]
 assert sum(e["args"]["value"] for e in retraces) >= 1  # fresh fn compiled
 print("OBS_8DEV_OK")

@@ -10,7 +10,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.polarfly import build_polarfly
 from repro.core.routing import build_routing
 from repro.core.topologies import build_jellyfish, build_slimfly
@@ -71,6 +71,7 @@ def _assert_identical(wl, r_ref, r_bat):
     np.testing.assert_array_equal(r_ref.latencies(), r_bat.latencies())
     np.testing.assert_array_equal(r_ref.occ_sum, r_bat.occ_sum)
     np.testing.assert_array_equal(r_ref.occ_max, r_bat.occ_max)
+    assert r_ref.admitted == r_bat.admitted
     _spot_check(wl, r_bat)
 
 
@@ -82,7 +83,7 @@ def _spot_check(wl, r):
     assert not (r.delivered & r.dropped).any()
     in_network_end = int(r.occ_sum[-1])
     assert r.num_delivered + r.num_dropped + in_network_end \
-        <= wl.num_packets
+        == r.admitted <= wl.num_packets
     assert (r.deliver_t[r.delivered] >= r.inject_t[r.delivered]).all()
 
 

@@ -1,7 +1,7 @@
 """Sharding rules + HLO cost parser units."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.launch.hlo import parse_module
 

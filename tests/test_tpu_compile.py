@@ -1,0 +1,127 @@
+"""Main-path programs compile for a TPU v5e chip that is described, not
+attached.
+
+The TPU compiler ships with jax, so these run on a CPU-only machine: each
+lowers a jitted program with shapes placed on a described ``v5e:2x2``
+device and asks the chip's compiler for an executable.  A refusal (an
+unsupported gather, too much memory) fails here instead of on the chip.
+Nothing runs, so no result or time is checked.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and under pytest-xdist
+every worker imports this file.  All such compiles stay in this one file,
+so one worker holds the library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
+
+from repro.core.polarfly import build_polarfly
+from repro.core.routing import _dest_device_fn, dest_block_size
+from repro.kernels.minplus.ops import path_costs
+from repro.parallel.compat import shard_map
+from repro.simulation import fluid, packet
+
+# PF(79) random_perm UGAL with 10 Valiant candidates: 6,319 flows, K = 11
+# candidates of at most L = 4 links, 505,600 directed links
+PF79_F, PF79_K, PF79_L, PF79_E = 6319, 11, 4, 505_600
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # an executable for a described chip is written to the persistent
+    # cache but cannot be read back without the chip: keep the cache off
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _on(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_path_costs_compiles_for_v5e_at_pf79(one_chip):
+    """The per-iteration path-cost gather at PF(79) shapes, as XLA's own
+    gather (no Pallas custom call) with next to no temporaries."""
+    compiled = jax.jit(path_costs).lower(
+        _on(one_chip, (PF79_E + 1,), jnp.float32),
+        _on(one_chip, (PF79_F, PF79_K, PF79_L), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= PF79_F * PF79_K * 4  # tile-padded
+    assert mem.temp_size_in_bytes < 2 ** 24
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_certified_saturation_compiles_for_v5e(one_chip):
+    """The certified UGAL bisection (conjugate Frank-Wolfe, line search,
+    gap bracket) at PF(7)-like shapes, padded-incidence link loads."""
+    f, k, l, e, w = 56, 8, 4, 456, 12
+    args = (_on(one_chip, (f, k, l), jnp.int32),
+            (_on(one_chip, (e, w), jnp.int32),), "pad",
+            _on(one_chip, (f, k), jnp.bool_),
+            _on(one_chip, (f, k), jnp.bool_),
+            _on(one_chip, (f,), jnp.int32),
+            _on(one_chip, (f,), jnp.float32))
+    compiled = fluid._certified_saturation.lower(
+        *args, e, "ugal", 0.05, 256, 3, "float32", 0).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_packet_scan_compiles_for_v5e(one_chip):
+    """The packet engine's scan (sort-based arbitration, UGAL injection
+    choice) at small shapes."""
+    f, k, l1, p, n, e = 56, 8, 5, 4000, 57, 456
+    i32 = jnp.int32
+    args = (_on(one_chip, (2, f, k, l1), i32), _on(one_chip, (2, f, k), i32),
+            _on(one_chip, (2, f), i32), _on(one_chip, (p + 1,), i32),
+            _on(one_chip, (p + 1,), i32), _on(one_chip, (2, p + 1), i32),
+            _on(one_chip, (n + 1,), i32), _on(one_chip, (f, k), i32),
+            _on(one_chip, (0,), i32), _on(one_chip, (), i32))
+    compiled = packet._run_batched.lower(
+        *args, e_num=e, size=4, capacity=32, adaptive=True, gated=False,
+        seg0=100, seg1=0).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_sharded_dest_columns_compile_for_v5e_2x2(topo, one_chip):
+    """The blockwise sharded backend's destination-column BFS twin (int16
+    distances) at PF(79), one block per chip of a 2x2 mesh: blocks are
+    independent, so the program holds no collective."""
+    g = build_polarfly(79).graph
+    _, indices = g.csr
+    block = dest_block_size(g.n, len(indices),
+                            g.padded_neighbors[0].shape[1])
+    fn = _dest_device_fn(g)
+    mesh = Mesh(np.asarray(topo.devices), ("blocks",))
+    spec = PartitionSpec("blocks")
+
+    def per_device(idx):
+        return tuple(o[None] for o in fn(idx[0]))
+
+    compiled = jax.jit(shard_map(per_device, mesh=mesh, in_specs=spec,
+                                 out_specs=spec)).lower(
+        jax.ShapeDtypeStruct((len(topo.devices), block), jnp.int32,
+                             sharding=NamedSharding(mesh, spec))).compile()
+    text = compiled.as_text()
+    assert not any(op in text for op in ("all-gather", "all-reduce",
+                                         "collective-permute", "all-to-all"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
