@@ -1,0 +1,12 @@
+"""`device_idle.min`: `device_idle.sat`'s reading, in the cells whose
+answer time is `sat_answer_s.min`."""
+
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+
+import harness  # noqa: E402
+
+read = harness.load_module("metrics", "device_idle.sat").read
