@@ -17,6 +17,7 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from ..obs.record import get_recorder
 from .gf import GF, is_prime_power
 from .graph import Graph
 
@@ -117,39 +118,48 @@ class PolarFly:
 
 
 def build_polarfly(q: int, chunk: int = 2048) -> PolarFly:
-    """Construct ER_q for any prime power q."""
+    """Construct ER_q for any prime power q.
+
+    Spans (``repro.obs``): ``polarfly.points`` (the field and the
+    projective points), ``polarfly.adjacency`` (the chunked all-pairs dot
+    products and the per-row neighbour lists), ``polarfly.classify``
+    (quadrics, V1, V2, the graph and the vertex index)."""
     if not is_prime_power(q):
         raise ValueError(f"q={q} must be a prime power")
-    gf = GF(q)
-    vt = _enumerate_projective_points(q)  # [N, 3]
+    rec = get_recorder()
+    with rec.span("polarfly.points", q=q):
+        gf = GF(q)
+        vt = _enumerate_projective_points(q)  # [N, 3]
     n = vt.shape[0]
     assert n == q * q + q + 1
 
     neighbors = []
     quadric = np.zeros(n, dtype=bool)
-    # chunked all-pairs dot products (tables are int32; N^2*3 lookups)
-    for lo in range(0, n, chunk):
-        hi = min(lo + chunk, n)
-        d = gf.dot3(vt[lo:hi, None, :], vt[None, :, :])  # [hi-lo, N]
-        for i in range(lo, hi):
-            row = d[i - lo]
-            nb = np.where(row == 0)[0]
-            if row[i] == 0:
-                quadric[i] = True
-                nb = nb[nb != i]
-            neighbors.append(nb.astype(np.int32))
+    with rec.span("polarfly.adjacency", n=n):
+        # chunked all-pairs dot products (tables are int32; N^2*3 lookups)
+        for lo in range(0, n, chunk):
+            hi = min(lo + chunk, n)
+            d = gf.dot3(vt[lo:hi, None, :], vt[None, :, :])  # [hi-lo, N]
+            for i in range(lo, hi):
+                row = d[i - lo]
+                nb = np.where(row == 0)[0]
+                if row[i] == 0:
+                    quadric[i] = True
+                    nb = nb[nb != i]
+                neighbors.append(nb.astype(np.int32))
 
-    v1 = np.zeros(n, dtype=bool)
-    for w in np.where(quadric)[0]:
-        v1[neighbors[w]] = True
-    v1 &= ~quadric
-    v2 = ~(quadric | v1)
+    with rec.span("polarfly.classify"):
+        v1 = np.zeros(n, dtype=bool)
+        for w in np.where(quadric)[0]:
+            v1[neighbors[w]] = True
+        v1 &= ~quadric
+        v2 = ~(quadric | v1)
 
-    graph = Graph(
-        f"PF({q})", n, neighbors,
-        params={"q": q, "radix": q + 1},
-        labels={"quadric": quadric, "v1": v1, "v2": v2, "vectors": vt},
-    )
-    index = {tuple(int(x) for x in vt[i]): i for i in range(n)}
+        graph = Graph(
+            f"PF({q})", n, neighbors,
+            params={"q": q, "radix": q + 1},
+            labels={"quadric": quadric, "v1": v1, "v2": v2, "vectors": vt},
+        )
+        index = {tuple(int(x) for x in vt[i]): i for i in range(n)}
     return PolarFly(q=q, gf=gf, graph=graph, vertices=vt,
                     quadric_mask=quadric, v1_mask=v1, v2_mask=v2, index=index)

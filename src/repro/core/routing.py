@@ -84,6 +84,7 @@ import numpy as np
 from .graph import Graph, UNREACHABLE
 from .polarfly import PolarFly
 from .stepping import walk_next_hops
+from ..obs.record import get_recorder
 from ..parallel.blockwise import (DEFAULT_BUDGET_BYTES, available_devices,
                                   block_size_for_budget, peak_bytes,
                                   plan_blocks, run_blocks)
@@ -526,13 +527,15 @@ def build_blocked_routing(g: Graph, block: Optional[int] = None,
     §IV; PolarStar is 3) can pass `diameter=` to skip the n-source BFS
     sweep -- at PF(157) scale (n = 24807) that sweep costs more than the
     path build it unlocks.  `backend`/`devices` carry through to every
-    column sweep the returned state serves.
+    column sweep the returned state serves.  The sweep runs in a
+    ``routing.diameter`` span (``repro.obs``).
     """
     if diameter is None:
         diam = 0
-        for _, db, _ in distance_blocks(g, budget_bytes=budget_bytes,
-                                        backend=backend, devices=devices):
-            diam = max(diam, int(db.max()))
+        with get_recorder().span("routing.diameter", n=g.n, backend=backend):
+            for _, db, _ in distance_blocks(g, budget_bytes=budget_bytes,
+                                            backend=backend, devices=devices):
+                diam = max(diam, int(db.max()))
     else:
         diam = int(diameter)
     if block is None:

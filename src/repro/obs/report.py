@@ -19,6 +19,8 @@ import json
 import sys
 from typing import Any, Dict, List, Optional, Sequence
 
+from .record import summarize_spans
+
 
 def load_events(path: str) -> List[Dict[str, Any]]:
     """Parse a trace JSONL file into a list of event dicts."""
@@ -37,7 +39,6 @@ def load_events(path: str) -> List[Dict[str, Any]]:
 
 def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Aggregate span/counter/gauge/histogram tables from raw events."""
-    spans: Dict[str, Dict[str, float]] = {}
     counters: Dict[str, float] = {}
     gauges: Dict[str, float] = {}
     histograms: Dict[str, Dict[str, int]] = {}
@@ -45,12 +46,7 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         ph = ev.get("ph")
         name = ev.get("name", "?")
         args = ev.get("args", {})
-        if ph == "X":
-            row = spans.setdefault(name, {"count": 0, "total_us": 0.0, "max_us": 0.0})
-            row["count"] += 1
-            row["total_us"] += float(ev.get("dur", 0.0))
-            row["max_us"] = max(row["max_us"], float(ev.get("dur", 0.0)))
-        elif ph == "C":
+        if ph == "C":
             v = float(args.get("value", 0.0))
             if args.get("gauge"):
                 gauges[name] = v
@@ -60,10 +56,8 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
             h = histograms.setdefault(name, {})
             for k, c in args["histogram"].items():
                 h[k] = h.get(k, 0) + int(c)
-    for row in spans.values():
-        row["mean_us"] = row["total_us"] / row["count"]
     return {
-        "spans": spans,
+        "spans": summarize_spans(events),
         "counters": counters,
         "gauges": gauges,
         "histograms": histograms,
@@ -82,10 +76,12 @@ def render_text(summary: Dict[str, Any]) -> str:
     lines: List[str] = []
     spans = summary["spans"]
     if spans:
-        lines.append(f"{'span':<40} {'count':>7} {'total':>10} {'mean':>10} {'max':>10}")
+        lines.append(f"{'span':<40} {'count':>7} {'total':>10} {'self':>10} "
+                     f"{'mean':>10} {'max':>10}")
         for name, row in sorted(spans.items(), key=lambda kv: -kv[1]["total_us"]):
             lines.append(
                 f"{name:<40} {row['count']:>7d} {_fmt_us(row['total_us']):>10} "
+                f"{_fmt_us(row['self_us']):>10} "
                 f"{_fmt_us(row['mean_us']):>10} {_fmt_us(row['max_us']):>10}"
             )
     if summary["counters"]:

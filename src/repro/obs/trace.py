@@ -107,16 +107,6 @@ class ConvergenceTrace:
             brackets=self.brackets[p : p + 1] if p < self.brackets.shape[0] else np.zeros((0, 4)),
         )
 
-    def to_metrics(self, recorder: Any, name: str = "fluid") -> None:
-        """Emit this trace into ``recorder`` as gauges and series."""
-        recorder.gauge(f"{name}.final_gap", self.final_gap)
-        if self.num_samples:
-            recorder.gauge(f"{name}.final_max_util", float(self.max_util[-1]))
-            recorder.series(f"{name}.gap", self.gap)
-            recorder.series(f"{name}.max_util", self.max_util)
-        recorder.gauge(f"{name}.samples", float(self.num_samples))
-        recorder.gauge(f"{name}.probes", float(self.num_probes))
-
     def __repr__(self) -> str:  # keep reprs readable in doctests/logs
         return (
             f"ConvergenceTrace(mode={self.mode!r}, kind={self.kind!r}, "

@@ -193,6 +193,13 @@ _MAPPED_CACHE: "OrderedDict[tuple, tuple]" = OrderedDict()
 _MAPPED_CACHE_SIZE = 16
 
 
+def _fn_label(fn: Callable) -> str:
+    """Module-qualified name of a device function, closures included
+    (``repro.core.routing._bfs_device_fn.fn``)."""
+    name = getattr(fn, "__qualname__", type(fn).__name__)
+    return f"{getattr(fn, '__module__', '?')}.{name.replace('.<locals>', '')}"
+
+
 def _mapped_fn(device_fn: Callable, devices: tuple) -> tuple:
     """(jitted shard_map of `device_fn`, the input sharding that puts
     block row j on ``devices[j]``), from the cross-call cache."""
@@ -207,11 +214,11 @@ def _mapped_fn(device_fn: Callable, devices: tuple) -> tuple:
         _MAPPED_CACHE.move_to_end(key)
         return hit
     # cache miss = a fresh shard_map wrapper = an XLA retrace on first
-    # call; surfaced as a counter so sweeps that accidentally rebuild
-    # their device_fn per call show up in the trace instead of just
-    # running mysteriously slow
-    get_recorder().counter("blockwise.retrace", 1,
-                           devices=len(devices))
+    # call; surfaced as a counter, labelled with the device function, so
+    # sweeps that accidentally rebuild their device_fn per call show up
+    # in the trace instead of just running mysteriously slow
+    get_recorder().counter("blockwise.retrace", 1, devices=len(devices),
+                           fn=_fn_label(device_fn))
 
     mesh = Mesh(np.asarray(devices), ("blocks",))
     spec = PartitionSpec("blocks")
@@ -250,7 +257,7 @@ def _run_sharded(items: np.ndarray, plan: BlockPlan,
                 blk = np.concatenate(
                     [blk, np.repeat(blk[-1:], plan.block - len(blk))])
             blocks.append(blk)
-        with jax.profiler.TraceAnnotation("blockwise.round"):
+        with get_recorder().span("blockwise.round", index=r):
             outs = mapped(jax.device_put(np.stack(blocks), sharding))
             owner = {s.index[0].start or 0: s.device.id
                      for s in outs[0].addressable_shards}

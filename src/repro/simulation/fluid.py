@@ -388,6 +388,12 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     batch tests pin the barrier-free program's results, and no TPU
     measurement yet says the barriers help there.
 
+    Device scopes (`jax.named_scope`, in the op_names of a profile):
+    `fluid.loads` (`loads`, either incidence path), `fluid.cost`
+    (`cost_of`, around `minplus.path_costs`), `fluid.target`
+    (`target_of`), `fluid.line_search` (the exact line search) and
+    `fluid.certify` (the gap and bracket at each chunk boundary).
+
     `cert_equilibrate(split0, demand, max_iters, util_tol, t0=0.0,
     decide_at=None, trace_cap=0)` returns `(split, rho, gap, mu_lb,
     mu_ub, iters, converged, trace)`.  With `trace_cap > 0` (a static
@@ -429,6 +435,7 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     def _barrier(x):
         return jax.lax.optimization_barrier(x) if barrier else x
 
+    @jax.named_scope("fluid.loads")
     def loads(split, demand):
         w = (split * demand[:, None]).reshape(-1)  # [F*K]
         if loads_kind == "pad":
@@ -442,11 +449,13 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
         rho = jnp.zeros(num_links + 1, w.dtype).at[eidx.reshape(-1)].add(w3.reshape(-1))  # reprolint: allow[scatter-add] -- deliberate fallback for pathologically skewed incidence where the padded gather would blow memory; FlowPaths.device_arrays picks the pad path whenever it fits
         return rho[:num_links]  # [E]
 
+    @jax.named_scope("fluid.cost")
     def cost_of(rho):
         delay = 1.0 + _queue_delay(rho)
         d = _barrier(jnp.concatenate([delay, jnp.zeros(1, delay.dtype)]))
         return path_costs(d, eidx)  # [F,K]
 
+    @jax.named_scope("fluid.target")
     def target_of(split, rho, cost):
         target = jax.nn.one_hot(jnp.argmin(cost, axis=1), split.shape[1],
                                 dtype=split.dtype)
@@ -532,6 +541,7 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     # gaps; it digs a deeper bracket first.
     ls_halvings = 20 if jnp.dtype(dtype) == jnp.float64 else 10
 
+    @jax.named_scope("fluid.line_search")
     def _line_search(rho, drho):
         """argmin_gamma Phi(rho + gamma * drho) over [0, 1]: bisection +
         false-position polish on the monotone derivative
@@ -593,6 +603,7 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
                     jnp.zeros((), jnp.int32), jnp.ones((), bool),
                     trace_single(z, rho0, mu0, mu0))
 
+        @jax.named_scope("fluid.certify")
         def residual(split, rho):
             cost = cost_of(rho)
             target = target_of(split, rho, jnp.where(valid, cost, jnp.inf))
@@ -646,6 +657,7 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
 
         traversals = (demand * lmax.astype(dtype)).sum()
 
+        @jax.named_scope("fluid.certify")
         def done_of(gap, rho):
             # abs: the gated-residual mode's gap can go negative
             resid = jnp.abs(gap)

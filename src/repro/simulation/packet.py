@@ -80,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.stepping import edge_sources
+from ..obs.record import get_recorder
 from ..parallel.blockwise import peak_bytes
 from .paths import DirectedEdges, FlowPaths, build_directed_edges, \
     build_flow_paths
@@ -277,77 +278,80 @@ def make_workload(fp: FlowPaths, offered: float, cycles: int, *,
     oblivious candidate draws -- comes from the single `rng`
     (`np.random.default_rng(seed)` when not given), in a fixed order, so
     equal seeds give identical workloads and therefore identical tail
-    metrics from either engine.
+    metrics from either engine.  Runs in a ``packet.workload`` span
+    (``repro.obs``).
     """
-    if rng is None:
-        rng = np.random.default_rng(seed)
-    pat = fp.pattern
-    nn = int(num_nodes if num_nodes is not None
-             else max(int(pat.src.max()), int(pat.dst.max())) + 1)
-    sel = np.arange(fp.pattern.num_flows)
-    if flow_sample is not None and flow_sample < len(sel):
-        sel = np.sort(rng.choice(len(sel), size=flow_sample, replace=False))
-    edges0, hops0, valid0 = (fp.edges[sel], fp.hops[sel], fp.valid[sel])
-    src, demand = pat.src[sel], pat.demand[sel]
-    eidx0, nv0 = _epoch_tables(fp, edges0, hops0, valid0)
-    if after is not None:
-        e1, h1, v1 = after
-        eidx1, nv1 = _epoch_tables(fp, e1[sel], h1[sel], v1[sel])
-        hops1 = h1[sel]
-        if switch_cycle is None:
-            raise ValueError("failure epoch needs switch_cycle")
-    else:
-        eidx1, nv1, hops1 = eidx0, nv0, hops0
-        switch_cycle = cycles
-    # epochs may disagree on max path length (re-routes around failures
-    # run longer): pad both to the wider hop budget with the exit marker
-    lmax = max(eidx0.shape[2], eidx1.shape[2])
-    pad_l = lambda a: np.concatenate(  # noqa: E731
-        [a, np.full(a.shape[:2] + (lmax - a.shape[2],), fp.num_links,
-                    dtype=np.int32)], axis=2)
-    eidx = np.stack([pad_l(eidx0), pad_l(eidx1)])
-    hops2 = np.stack([hops0.astype(np.int32), hops1.astype(np.int32)])
-    n_valid = np.stack([nv0, nv1])
+    with get_recorder().span("packet.workload", cycles=cycles):
+        if rng is None:
+            rng = np.random.default_rng(seed)
+        pat = fp.pattern
+        nn = int(num_nodes if num_nodes is not None
+                 else max(int(pat.src.max()), int(pat.dst.max())) + 1)
+        sel = np.arange(fp.pattern.num_flows)
+        if flow_sample is not None and flow_sample < len(sel):
+            sel = np.sort(rng.choice(len(sel), size=flow_sample,
+                                     replace=False))
+        edges0, hops0, valid0 = (fp.edges[sel], fp.hops[sel], fp.valid[sel])
+        src, demand = pat.src[sel], pat.demand[sel]
+        eidx0, nv0 = _epoch_tables(fp, edges0, hops0, valid0)
+        if after is not None:
+            e1, h1, v1 = after
+            eidx1, nv1 = _epoch_tables(fp, e1[sel], h1[sel], v1[sel])
+            hops1 = h1[sel]
+            if switch_cycle is None:
+                raise ValueError("failure epoch needs switch_cycle")
+        else:
+            eidx1, nv1, hops1 = eidx0, nv0, hops0
+            switch_cycle = cycles
+        # epochs may disagree on max path length (re-routes around failures
+        # run longer): pad both to the wider hop budget with the exit marker
+        lmax = max(eidx0.shape[2], eidx1.shape[2])
+        pad_l = lambda a: np.concatenate(  # noqa: E731
+            [a, np.full(a.shape[:2] + (lmax - a.shape[2],), fp.num_links,
+                        dtype=np.int32)], axis=2)
+        eidx = np.stack([pad_l(eidx0), pad_l(eidx1)])
+        hops2 = np.stack([hops0.astype(np.int32), hops1.astype(np.int32)])
+        n_valid = np.stack([nv0, nv1])
 
-    # last failed hop per epoch-0 candidate (L + 1 when the path is
-    # clean); the drop test `hop <= fail_hop` must see the *last* failed
-    # link, or a packet past one failure but short of a second survives
-    l1 = eidx.shape[3]
-    if failed_edges is not None and len(failed_edges):
-        fmask = np.zeros(fp.num_links + 1, dtype=bool)
-        fmask[np.asarray(failed_edges, dtype=np.int64)] = True
-        onpath = fmask[eidx0]  # [F, K, L0 + 1] (pre-pad width)
-        anyf = onpath.any(axis=2)
-        last = onpath.shape[2] - 1 - onpath[:, :, ::-1].argmax(axis=2)
-        fail_hop = np.where(anyf, last, l1).astype(np.int32)
-    else:
-        fail_hop = np.full(hops0.shape, l1, dtype=np.int32)
+        # last failed hop per epoch-0 candidate (L + 1 when the path is
+        # clean); the drop test `hop <= fail_hop` must see the *last* failed
+        # link, or a packet past one failure but short of a second survives
+        l1 = eidx.shape[3]
+        if failed_edges is not None and len(failed_edges):
+            fmask = np.zeros(fp.num_links + 1, dtype=bool)
+            fmask[np.asarray(failed_edges, dtype=np.int64)] = True
+            onpath = fmask[eidx0]  # [F, K, L0 + 1] (pre-pad width)
+            anyf = onpath.any(axis=2)
+            last = onpath.shape[2] - 1 - onpath[:, :, ::-1].argmax(axis=2)
+            fail_hop = np.where(anyf, last, l1).astype(np.int32)
+        else:
+            fail_hop = np.full(hops0.shape, l1, dtype=np.int32)
 
-    phase = rng.random(len(sel))
-    bphase = (rng.integers(burst.period, size=len(sel))
-              if burst is not None else np.zeros(len(sel), np.int64))
-    pkt_flow, pkt_t = _injection_times(demand, offered, size, cycles, burst,
-                                       phase, bphase)
-    if len(pkt_flow) > max_packets:
-        raise ValueError(
-            f"{len(pkt_flow)} packets exceed max_packets={max_packets}; "
-            "lower offered/cycles or pass flow_sample")
-    # id order = (source router, arrival cycle, flow): per-source FIFO
-    order = np.lexsort((pkt_flow, pkt_t, src[pkt_flow]))
-    pkt_flow, pkt_t = pkt_flow[order], pkt_t[order]
-    src_off = np.searchsorted(src[pkt_flow], np.arange(nn + 1),
-                              side="left").astype(np.int64)
-    u = rng.random(len(pkt_flow))
-    pkt_cand = np.stack([
-        np.minimum((u * n_valid[ep, pkt_flow]).astype(np.int32),
-                   n_valid[ep, pkt_flow] - 1)
-        for ep in (0, 1)])
-    return PacketWorkload(
-        eidx=eidx, hops=hops2, n_valid=n_valid, pkt_flow=pkt_flow,
-        pkt_t=pkt_t, pkt_cand=pkt_cand, src_off=src_off,
-        num_links=fp.num_links, num_nodes=nn, size=size, capacity=capacity,
-        cycles=cycles, mode=fp.mode, switch_cycle=int(switch_cycle),
-        fail_hop=fail_hop, pattern_name=pat.name)
+        phase = rng.random(len(sel))
+        bphase = (rng.integers(burst.period, size=len(sel))
+                  if burst is not None else np.zeros(len(sel), np.int64))
+        pkt_flow, pkt_t = _injection_times(demand, offered, size, cycles,
+                                           burst, phase, bphase)
+        if len(pkt_flow) > max_packets:
+            raise ValueError(
+                f"{len(pkt_flow)} packets exceed max_packets={max_packets}; "
+                "lower offered/cycles or pass flow_sample")
+        # id order = (source router, arrival cycle, flow): per-source FIFO
+        order = np.lexsort((pkt_flow, pkt_t, src[pkt_flow]))
+        pkt_flow, pkt_t = pkt_flow[order], pkt_t[order]
+        src_off = np.searchsorted(src[pkt_flow], np.arange(nn + 1),
+                                  side="left").astype(np.int64)
+        u = rng.random(len(pkt_flow))
+        pkt_cand = np.stack([
+            np.minimum((u * n_valid[ep, pkt_flow]).astype(np.int32),
+                       n_valid[ep, pkt_flow] - 1)
+            for ep in (0, 1)])
+        return PacketWorkload(
+            eidx=eidx, hops=hops2, n_valid=n_valid, pkt_flow=pkt_flow,
+            pkt_t=pkt_t, pkt_cand=pkt_cand, src_off=src_off,
+            num_links=fp.num_links, num_nodes=nn, size=size, capacity=capacity,
+            cycles=cycles, mode=fp.mode, switch_cycle=int(switch_cycle),
+            fail_hop=fail_hop, pattern_name=pat.name)
 
 
 def build_failure_workload(rt, rt_after, pattern: TrafficPattern, mode: str,
@@ -470,7 +474,6 @@ def record_occupancy(res: PacketResult, name: str = "packet",
     returns the summary dict.  Host-side numpy only -- the batched
     engine's scan outputs have already been fetched by the time a
     `PacketResult` exists."""
-    from ..obs.record import get_recorder
     rec = recorder if recorder is not None else get_recorder()
     occ_sum = np.asarray(res.occ_sum)
     occ_max = np.asarray(res.occ_max)
@@ -692,7 +695,10 @@ def _run_batched(eidx, hops, n_valid, pkt_flow, pkt_t, pkt_cand, src_off,
     per-packet hop/chosen/epoch/outcome -- and every per-cycle update is
     gathers, one stable argsort (arbitration order), segmented ranks via
     searchsorted, and unique-index `.at[].set` scatters.  No host syncs,
-    no [n, n] anything, no scatter-add."""
+    no [n, n] anything, no scatter-add.  Device scopes of a cycle:
+    `packet.route` (the per-hop and injection gathers),
+    `packet.arbitrate` (the stable argsort and the segmented-rank
+    searchsorteds), `packet.queues` (the `.at[].set` scatters)."""
     q_cap = capacity
     p_pad = pkt_flow.shape[0] - 1  # static pad slot == P
     gate = _gate_occ(q_cap)
@@ -700,93 +706,96 @@ def _run_batched(eidx, hops, n_valid, pkt_flow, pkt_t, pkt_cand, src_off,
     def step(ep_now: int):
         def _step(state, t):
             queues, occ, serve, hop, chosen, ep_pkt, ptr, dlv_t, dlv = state
-            heads = queues[:e_num, 0]
-            nonempty = occ > 0
-            serve = jnp.where(nonempty & (serve > 0), serve - 1, serve)
-            ready = nonempty & (serve == 0)
-            # in-flight intents
-            hf = pkt_flow[heads]
-            nxt = eidx[ep_pkt[heads], hf, chosen[heads], hop[heads] + 1]
-            exit_ = ready & (nxt == e_num)
-            mover = ready & (nxt < e_num)
-            tgt = jnp.where(mover, nxt, e_num)
-            # injection intents (one bid per source; first links are
-            # source-distinct, so bids never collide on a target)
-            have = ptr < src_off[1:]
-            bid_p = jnp.where(have, ptr, p_pad)
-            pend = have & (pkt_t[bid_p] <= t)
-            pf = pkt_flow[bid_p]
-            occ_pad = jnp.concatenate([occ, jnp.zeros(1, jnp.int32)])
-            if adaptive:
-                firsts = eidx[ep_now, pf, :, 0]          # [S, K]
-                cost = hops[ep_now, pf] + occ_pad[firsts]
-                k = eidx.shape[2]
-                ok = jnp.arange(k) < n_valid[ep_now, pf][:, None]
-                c = jnp.argmin(jnp.where(ok, cost, _BIG),
-                               axis=1).astype(jnp.int32)
-                if gated:
-                    c = jnp.where(occ_pad[eidx[ep_now, pf, 0, 0]] >= gate,
-                                  c, 0)
-            else:
-                c = pkt_cand[ep_now, bid_p]
-            itgt = jnp.where(pend, eidx[ep_now, pf, c, 0], e_num)
-            # arbitration: stable sort by target, rank within segment
-            free = q_cap - occ
-            order = jnp.argsort(tgt, stable=True)
-            st = tgt[order]
-            rank = (jnp.arange(e_num, dtype=jnp.int32)
-                    - jnp.searchsorted(st, st, side="left"
-                                       ).astype(jnp.int32))
-            free_pad = jnp.concatenate([free, jnp.zeros(1, jnp.int32)])
-            acc_s = (st < e_num) & (rank < free_pad[st])
-            eids = jnp.arange(e_num, dtype=jnp.int32)
-            cnt_cand = (jnp.searchsorted(st, eids, side="right")
-                        - jnp.searchsorted(st, eids, side="left")
-                        ).astype(jnp.int32)
-            acc_cnt = jnp.minimum(cnt_cand, free)
-            acc_cnt_pad = jnp.concatenate([acc_cnt,
-                                           jnp.zeros(1, jnp.int32)])
-            inj_acc = pend & (itgt < e_num) \
-                & (acc_cnt_pad[itgt] < free_pad[itgt])
-            # apply: pops (exits + accepted movers) ...
-            acc_lin = jnp.zeros(e_num, bool).at[order].set(acc_s)
-            dep = exit_ | acc_lin
-            dep_pad = jnp.concatenate([dep, jnp.zeros(1, bool)])
-            shifted = jnp.concatenate(
-                [queues[:, 1:],
-                 jnp.full((queues.shape[0], 1), p_pad, jnp.int32)], axis=1)
-            queues = jnp.where(dep_pad[:, None], shifted, queues)
-            occ_dep = occ - dep.astype(jnp.int32)
-            occ_dep_pad = jnp.concatenate([occ_dep,
-                                           jnp.zeros(1, jnp.int32)])
-            # ... then pushes: movers land at base + rank, the bid after
-            mrow = jnp.where(acc_s, st, e_num)
-            mpos = jnp.clip(occ_dep_pad[st] + rank, 0, q_cap - 1)
-            mpid = heads[order]
-            queues = queues.at[mrow, mpos].set(
-                jnp.where(acc_s, mpid, queues[mrow, mpos]))
-            irow = jnp.where(inj_acc, itgt, e_num)
-            ipos = jnp.clip(occ_dep_pad[itgt] + acc_cnt_pad[itgt], 0,
-                            q_cap - 1)
-            queues = queues.at[irow, ipos].set(
-                jnp.where(inj_acc, bid_p, queues[irow, ipos]))
-            inj_lin = jnp.zeros(e_num + 1, jnp.int32).at[irow].set(
-                inj_acc.astype(jnp.int32))
-            occ = occ_dep + acc_cnt + inj_lin[:e_num]
-            # per-packet bookkeeping (unique pids per scatter)
-            hop = hop.at[jnp.where(acc_s, mpid, p_pad)].set(
-                hop[mpid] + 1)
-            hop = hop.at[jnp.where(inj_acc, bid_p, p_pad)].set(0)
-            chosen = chosen.at[jnp.where(inj_acc, bid_p, p_pad)].set(c)
-            ep_pkt = ep_pkt.at[jnp.where(inj_acc, bid_p, p_pad)].set(
-                jnp.int32(ep_now))
-            dpid = jnp.where(exit_, heads, p_pad)
-            dlv_t = dlv_t.at[dpid].set(t)
-            dlv = dlv.at[dpid].set(True)
-            dlv = dlv.at[p_pad].set(False)
-            ptr = ptr + inj_acc.astype(jnp.int32)
-            # head changes restart serialization
-            serve = jnp.where(queues[:e_num, 0] != heads, size, serve)
+            with jax.named_scope("packet.route"):
+                heads = queues[:e_num, 0]
+                nonempty = occ > 0
+                serve = jnp.where(nonempty & (serve > 0), serve - 1, serve)
+                ready = nonempty & (serve == 0)
+                # in-flight intents
+                hf = pkt_flow[heads]
+                nxt = eidx[ep_pkt[heads], hf, chosen[heads], hop[heads] + 1]
+                exit_ = ready & (nxt == e_num)
+                mover = ready & (nxt < e_num)
+                tgt = jnp.where(mover, nxt, e_num)
+                # injection intents (one bid per source; first links are
+                # source-distinct, so bids never collide on a target)
+                have = ptr < src_off[1:]
+                bid_p = jnp.where(have, ptr, p_pad)
+                pend = have & (pkt_t[bid_p] <= t)
+                pf = pkt_flow[bid_p]
+                occ_pad = jnp.concatenate([occ, jnp.zeros(1, jnp.int32)])
+                if adaptive:
+                    firsts = eidx[ep_now, pf, :, 0]          # [S, K]
+                    cost = hops[ep_now, pf] + occ_pad[firsts]
+                    k = eidx.shape[2]
+                    ok = jnp.arange(k) < n_valid[ep_now, pf][:, None]
+                    c = jnp.argmin(jnp.where(ok, cost, _BIG),
+                                   axis=1).astype(jnp.int32)
+                    if gated:
+                        c = jnp.where(occ_pad[eidx[ep_now, pf, 0, 0]] >= gate,
+                                      c, 0)
+                else:
+                    c = pkt_cand[ep_now, bid_p]
+                itgt = jnp.where(pend, eidx[ep_now, pf, c, 0], e_num)
+            with jax.named_scope("packet.arbitrate"):
+                # arbitration: stable sort by target, rank within segment
+                free = q_cap - occ
+                order = jnp.argsort(tgt, stable=True)
+                st = tgt[order]
+                rank = (jnp.arange(e_num, dtype=jnp.int32)
+                        - jnp.searchsorted(st, st, side="left"
+                                           ).astype(jnp.int32))
+                free_pad = jnp.concatenate([free, jnp.zeros(1, jnp.int32)])
+                acc_s = (st < e_num) & (rank < free_pad[st])
+                eids = jnp.arange(e_num, dtype=jnp.int32)
+                cnt_cand = (jnp.searchsorted(st, eids, side="right")
+                            - jnp.searchsorted(st, eids, side="left")
+                            ).astype(jnp.int32)
+                acc_cnt = jnp.minimum(cnt_cand, free)
+                acc_cnt_pad = jnp.concatenate([acc_cnt,
+                                               jnp.zeros(1, jnp.int32)])
+                inj_acc = pend & (itgt < e_num) \
+                    & (acc_cnt_pad[itgt] < free_pad[itgt])
+            with jax.named_scope("packet.queues"):
+                # apply: pops (exits + accepted movers) ...
+                acc_lin = jnp.zeros(e_num, bool).at[order].set(acc_s)
+                dep = exit_ | acc_lin
+                dep_pad = jnp.concatenate([dep, jnp.zeros(1, bool)])
+                shifted = jnp.concatenate(
+                    [queues[:, 1:],
+                     jnp.full((queues.shape[0], 1), p_pad, jnp.int32)], axis=1)
+                queues = jnp.where(dep_pad[:, None], shifted, queues)
+                occ_dep = occ - dep.astype(jnp.int32)
+                occ_dep_pad = jnp.concatenate([occ_dep,
+                                               jnp.zeros(1, jnp.int32)])
+                # ... then pushes: movers land at base + rank, the bid after
+                mrow = jnp.where(acc_s, st, e_num)
+                mpos = jnp.clip(occ_dep_pad[st] + rank, 0, q_cap - 1)
+                mpid = heads[order]
+                queues = queues.at[mrow, mpos].set(
+                    jnp.where(acc_s, mpid, queues[mrow, mpos]))
+                irow = jnp.where(inj_acc, itgt, e_num)
+                ipos = jnp.clip(occ_dep_pad[itgt] + acc_cnt_pad[itgt], 0,
+                                q_cap - 1)
+                queues = queues.at[irow, ipos].set(
+                    jnp.where(inj_acc, bid_p, queues[irow, ipos]))
+                inj_lin = jnp.zeros(e_num + 1, jnp.int32).at[irow].set(
+                    inj_acc.astype(jnp.int32))
+                occ = occ_dep + acc_cnt + inj_lin[:e_num]
+                # per-packet bookkeeping (unique pids per scatter)
+                hop = hop.at[jnp.where(acc_s, mpid, p_pad)].set(
+                    hop[mpid] + 1)
+                hop = hop.at[jnp.where(inj_acc, bid_p, p_pad)].set(0)
+                chosen = chosen.at[jnp.where(inj_acc, bid_p, p_pad)].set(c)
+                ep_pkt = ep_pkt.at[jnp.where(inj_acc, bid_p, p_pad)].set(
+                    jnp.int32(ep_now))
+                dpid = jnp.where(exit_, heads, p_pad)
+                dlv_t = dlv_t.at[dpid].set(t)
+                dlv = dlv.at[dpid].set(True)
+                dlv = dlv.at[p_pad].set(False)
+                ptr = ptr + inj_acc.astype(jnp.int32)
+                # head changes restart serialization
+                serve = jnp.where(queues[:e_num, 0] != heads, size, serve)
             return ((queues, occ, serve, hop, chosen, ep_pkt, ptr, dlv_t,
                      dlv),
                     (occ.sum(), jnp.max(occ, initial=0), occ[record]))

@@ -55,6 +55,7 @@ from ..core.routing import (RoutingTables, dest_block_peak_bytes,
                             minimal_path, minimal_paths)
 from ..core.stepping import (edge_walk, successor_tables, walk_next_hops,
                              walk_successors)
+from ..obs.record import get_recorder
 from ..parallel.blockwise import (DEFAULT_BUDGET_BYTES, block_size_for_budget,
                                   peak_bytes, plan_blocks, run_blocks)
 from .traffic import TrafficPattern
@@ -154,9 +155,10 @@ class DirectedEdges:
 def build_directed_edges(g: Graph) -> DirectedEdges:
     # the directed edge id space IS the graph's CSR layout; the padded
     # neighbor view is shared with the graph's per-instance cache
-    indptr, indices = g.csr
-    return DirectedEdges(indptr, indices, int(indptr[-1]),
-                         _nb_pad=g.padded_neighbors)
+    with get_recorder().span("paths.edges"):
+        indptr, indices = g.csr
+        return DirectedEdges(indptr, indices, int(indptr[-1]),
+                             _nb_pad=g.padded_neighbors)
 
 
 @dataclass
@@ -194,34 +196,40 @@ class FlowPaths:
                     edge's own load rather than a global prefix sum).
           hops      [F, K] int32 per-candidate hop counts (batched engine
                     computes mean hops in-jit).
+
+        The first call runs in a ``paths.incidence`` span (``repro.obs``):
+        the host work and the uploads, not their completion.
         """
         if self._device is None:
-            import jax.numpy as jnp
-            f, k, l = self.edges.shape
-            flat = self.edges.reshape(-1)
-            real = flat >= 0
-            nnz = int(real.sum())
-            fk = np.repeat(np.arange(f * k, dtype=np.int32), l)[real]
-            e_of = flat[real]
-            order = np.argsort(e_of, kind="stable")
-            counts = np.bincount(e_of, minlength=self.num_links)
-            w_max = int(counts.max()) if nnz else 0
-            if self.num_links * w_max <= max(4 * nnz, _INC_PAD_MAX_ENTRIES):
-                inc = np.full((self.num_links, w_max), f * k, dtype=np.int32)
-                cols = np.concatenate([np.arange(c) for c in counts]) \
-                    if nnz else np.zeros(0, dtype=np.int64)
-                inc[e_of[order], cols] = fk[order]
-                loads_rep = ("pad", jnp.asarray(inc))
-            else:
-                loads_rep = ("scatter",)
-            eidx = np.where(self.edges >= 0, self.edges, self.num_links)
-            self._device = (jnp.asarray(eidx.astype(np.int32)), loads_rep,
-                            jnp.asarray(self.valid),
-                            jnp.asarray(self.is_min),
-                            jnp.asarray(self.first_edge),
-                            jnp.asarray(self.pattern.demand),
-                            jnp.asarray(self.hops))
+            with get_recorder().span("paths.incidence"):
+                self._device = self._upload()
         return self._device
+
+    def _upload(self) -> tuple:
+        """Build the arrays `device_arrays` returns and upload them."""
+        import jax.numpy as jnp
+        f, k, l = self.edges.shape
+        flat = self.edges.reshape(-1)
+        real = flat >= 0
+        nnz = int(real.sum())
+        fk = np.repeat(np.arange(f * k, dtype=np.int32), l)[real]
+        e_of = flat[real]
+        order = np.argsort(e_of, kind="stable")
+        counts = np.bincount(e_of, minlength=self.num_links)
+        w_max = int(counts.max()) if nnz else 0
+        if self.num_links * w_max <= max(4 * nnz, _INC_PAD_MAX_ENTRIES):
+            inc = np.full((self.num_links, w_max), f * k, dtype=np.int32)
+            cols = np.concatenate([np.arange(c) for c in counts]) \
+                if nnz else np.zeros(0, dtype=np.int64)
+            inc[e_of[order], cols] = fk[order]
+            loads_rep = ("pad", jnp.asarray(inc))
+        else:
+            loads_rep = ("scatter",)
+        eidx = np.where(self.edges >= 0, self.edges, self.num_links)
+        return (jnp.asarray(eidx.astype(np.int32)), loads_rep,
+                jnp.asarray(self.valid), jnp.asarray(self.is_min),
+                jnp.asarray(self.first_edge),
+                jnp.asarray(self.pattern.demand), jnp.asarray(self.hops))
 
     @classmethod
     def concat(cls, chunks: Sequence["FlowPaths"]) -> "FlowPaths":
@@ -616,6 +624,22 @@ def blocked_paths_peak_bytes(n: int, e_dir: int, deg_max: int,
                                                      block))
 
 
+def _swept(blocks: Iterator[tuple]) -> Iterator[tuple]:
+    """The blocks of a `dest_blocks` column sweep, each fetched in a
+    ``paths.sweep`` span (from its dispatch until its columns are on the
+    host) and consumed in a ``paths.walk`` span (the caller's loop body,
+    which runs while this generator is suspended inside that span)."""
+    rec = get_recorder()
+    blocks = iter(blocks)
+    while True:
+        with rec.span("paths.sweep"):
+            blk = next(blocks, None)
+        if blk is None:
+            return
+        with rec.span("paths.walk"):
+            yield blk
+
+
 def _build_blocked(rt, pattern: TrafficPattern, mode: str,
                    k_candidates: int, seed: int,
                    draws: Optional[Dict[str, np.ndarray]] = None
@@ -635,54 +659,62 @@ def _build_blocked(rt, pattern: TrafficPattern, mode: str,
     outputs are bit-identical for equal arguments; `build_flow_paths_chunks`
     passes row slices of a full-batch draw via `draws`, which is what makes
     chunked assembly bit-identical to the monolithic build.
+
+    Spans (``repro.obs``): ``paths.edges``, then ``paths.sweep`` for each
+    column block and ``paths.walk`` for the host work around them (draws,
+    walks, stitching), never nested in each other.
     """
     g = rt.graph
     de = build_directed_edges(g)
-    n = g.n
-    f = pattern.num_flows
-    src = pattern.src.astype(np.int64)
-    dst = pattern.dst.astype(np.int64)
+    rec = get_recorder()
+    with rec.span("paths.walk"):
+        n = g.n
+        f = pattern.num_flows
+        src = pattern.src.astype(np.int64)
+        dst = pattern.dst.astype(np.int64)
 
-    include_min, alt_kind, k_alt, k_total = _mode_layout(mode, k_candidates)
-    diam = rt.diameter
-    lmax = 2 * max(2, diam)
-    nb, deg = de.padded_neighbors()
-    dmax = int(deg.max()) if len(deg) else 0
-    if draws is None:
-        draws = _draw_randomness(np.random.default_rng(seed), alt_kind, f,
-                                 k_total if mode == "ecmp" else k_alt,
-                                 n, dmax, diam)
+        include_min, alt_kind, k_alt, k_total = _mode_layout(mode,
+                                                             k_candidates)
+        diam = rt.diameter
+        lmax = 2 * max(2, diam)
+        nb, deg = de.padded_neighbors()
+        dmax = int(deg.max()) if len(deg) else 0
+        if draws is None:
+            draws = _draw_randomness(np.random.default_rng(seed), alt_kind, f,
+                                     k_total if mode == "ecmp" else k_alt,
+                                     n, dmax, diam)
 
-    edges = -np.ones((f, k_total, lmax), dtype=np.int32)
-    hops = np.zeros((f, k_total), dtype=np.int32)
-    valid = np.zeros((f, k_total), dtype=bool)
-    is_min = np.zeros((f, k_total), dtype=bool)
+        edges = -np.ones((f, k_total, lmax), dtype=np.int32)
+        hops = np.zeros((f, k_total), dtype=np.int32)
+        valid = np.zeros((f, k_total), dtype=bool)
+        is_min = np.zeros((f, k_total), dtype=bool)
 
-    present = nb >= 0
-    safe_nb = np.where(present, nb, 0)
-    # destinations per column block: the successor/column entry cap, further
-    # tightened by the routing state's own byte-budget block when it has one
-    # (BlockedRouting carries the bfs budget; RoutingTables slices for free)
-    block = _dest_block(n, dmax)
-    rt_block = getattr(rt, "block", None)
-    if rt_block is not None:
-        block = min(block, rt_block)
-    col = 1 if include_min else 0
+        present = nb >= 0
+        safe_nb = np.where(present, nb, 0)
+        # destinations per column block: the successor/column entry cap,
+        # further tightened by the routing state's own byte-budget block
+        # when it has one (BlockedRouting carries the bfs budget;
+        # RoutingTables slices for free)
+        block = _dest_block(n, dmax)
+        rt_block = getattr(rt, "block", None)
+        if rt_block is not None:
+            block = min(block, rt_block)
+        col = 1 if include_min else 0
 
-    min_e = np.full((f, diam), -1, dtype=np.int32)  # reprolint: allow[sentinel] -- -1 pads unused hop slots of the [F, diam] edge matrix; consumers mask on hop count
-    min_h = np.zeros(f, dtype=np.int32)
-    if alt_kind in ("valiant", "cvaliant"):
-        s_rep = np.broadcast_to(src[:, None], (f, k_alt)).reshape(-1)
-        d_rep = np.broadcast_to(dst[:, None], (f, k_alt)).reshape(-1)
-        r_all = _skip2(draws["RV"].reshape(-1), s_rep, d_rep)  # [F * K]
-        e2 = -np.ones((f * k_alt, diam), dtype=np.int32)  # r->d segments
-        h2 = np.zeros(f * k_alt, dtype=np.int32)
-        adj = np.zeros(f, dtype=bool)
+        min_e = np.full((f, diam), -1, dtype=np.int32)  # reprolint: allow[sentinel] -- -1 pads unused hop slots of the [F, diam] edge matrix; consumers mask on hop count
+        min_h = np.zeros(f, dtype=np.int32)
+        if alt_kind in ("valiant", "cvaliant"):
+            s_rep = np.broadcast_to(src[:, None], (f, k_alt)).reshape(-1)
+            d_rep = np.broadcast_to(dst[:, None], (f, k_alt)).reshape(-1)
+            r_all = _skip2(draws["RV"].reshape(-1), s_rep, d_rep)  # [F * K]
+            e2 = -np.ones((f * k_alt, diam), dtype=np.int32)  # r->d segments
+            h2 = np.zeros(f * k_alt, dtype=np.int32)
+            adj = np.zeros(f, dtype=bool)
 
+        uniq, inv = np.unique(dst, return_inverse=True)
+        off = 0
     # ---- pass 1: flow-destination blocks --------------------------------
-    uniq, inv = np.unique(dst, return_inverse=True)
-    off = 0
-    for dblk, dist_cols, nh_cols in rt.dest_blocks(uniq, block):
+    for dblk, dist_cols, nh_cols in _swept(rt.dest_blocks(uniq, block)):
         b = len(dblk)
         fsel = np.flatnonzero((inv >= off) & (inv < off + b))
         ld = inv[fsel] - off
@@ -738,35 +770,38 @@ def _build_blocked(rt, pattern: TrafficPattern, mode: str,
             seg = (np.flatnonzero(adj)[:, None] * k_alt
                    + np.arange(k_alt)[None, :]).reshape(-1)
         if len(seg):
-            e1 = np.empty((len(seg), diam), dtype=np.int32)
-            h1 = np.empty(len(seg), dtype=np.int32)
-            r_seg, s_seg = r_all[seg], s_rep[seg]
-            uniq_r, inv_r = np.unique(r_seg, return_inverse=True)
+            with rec.span("paths.walk"):
+                e1 = np.empty((len(seg), diam), dtype=np.int32)
+                h1 = np.empty(len(seg), dtype=np.int32)
+                r_seg, s_seg = r_all[seg], s_rep[seg]
+                uniq_r, inv_r = np.unique(r_seg, return_inverse=True)
             off_r = 0
-            for dblk, _, nh_cols in rt.dest_blocks(uniq_r, block):
+            for dblk, _, nh_cols in _swept(rt.dest_blocks(uniq_r, block)):
                 b = len(dblk)
                 ssel = np.flatnonzero((inv_r >= off_r) & (inv_r < off_r + b))
                 e1[ssel], h1[ssel] = _walk_edges_block(
                     de, nh_cols, s_seg[ssel], inv_r[ssel] - off_r,
                     r_seg[ssel], diam)
                 off_r += b
-            ev = _stitch(e1, h1, e2[seg], lmax)
-            hv = (h1 + h2[seg]).astype(np.int32)
-            rows, cols = seg // k_alt, col + (seg % k_alt)
-            edges[rows, cols] = ev
-            hops[rows, cols] = hv
-            valid[rows, cols] = True
+            with rec.span("paths.walk"):
+                ev = _stitch(e1, h1, e2[seg], lmax)
+                hv = (h1 + h2[seg]).astype(np.int32)
+                rows, cols = seg // k_alt, col + (seg % k_alt)
+                edges[rows, cols] = ev
+                hops[rows, cols] = hv
+                valid[rows, cols] = True
 
-    first_edge = (min_e[:, 0].copy() if min_e.shape[1]
-                  else np.zeros(f, dtype=np.int32))
-    if include_min:
-        edges[:, 0, :min_e.shape[1]] = min_e
-        hops[:, 0] = min_h
-        valid[:, 0] = True
-        is_min[:, 0] = True
-    return FlowPaths(pattern=pattern, edges=edges, hops=hops, valid=valid,
-                     is_min=is_min, first_edge=first_edge, num_links=de.num,
-                     mode=mode)
+    with rec.span("paths.walk"):
+        first_edge = (min_e[:, 0].copy() if min_e.shape[1]
+                      else np.zeros(f, dtype=np.int32))
+        if include_min:
+            edges[:, 0, :min_e.shape[1]] = min_e
+            hops[:, 0] = min_h
+            valid[:, 0] = True
+            is_min[:, 0] = True
+        return FlowPaths(pattern=pattern, edges=edges, hops=hops,
+                         valid=valid, is_min=is_min, first_edge=first_edge,
+                         num_links=de.num, mode=mode)
 
 
 # --------------------------------------------------------------------------

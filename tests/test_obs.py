@@ -54,7 +54,9 @@ def _golden_recorder() -> Recorder:
         sp.set(probes=2)
         with rec.span("inner"):
             pass
+    rec.request(3)  # tags the counter event alone
     rec.counter("retrace", 1, devices=8)
+    rec.request(None)
     rec.gauge("sat", 0.375)
     rec.histogram("depth", [0, 1, 1, 3])
     rec.series("occ", [0.0, 1.0, 2.0, 3.0], max_points=2)
@@ -207,18 +209,6 @@ def test_uncertified_trace_is_free_of_side_effects():
     assert np.isnan(res.truncation_err)  # only return_info computes it
     with pytest.raises(ValueError, match="trace=True"):
         saturation_throughput(fp, trace=True, engine="scalar")
-
-
-def test_trace_to_metrics_emits_gauges_and_series():
-    fp = _pf7_flow_paths("ugal")
-    res = saturation_throughput(fp, tol=0.05, iters=64, engine="batched",
-                                trace=True)
-    rec = Recorder()
-    res.trace.to_metrics(rec, name="fluid")
-    met = rec.metrics()
-    assert met["gauges"]["fluid.final_gap"]["last"] == res.trace.final_gap
-    names = {ev["name"] for ev in rec.events()}
-    assert {"fluid.gap", "fluid.max_util"} <= names
 
 
 # ---------------------------------------------------------------------------
