@@ -104,7 +104,7 @@ import jax.numpy as jnp
 from ..kernels.minplus.ops import path_costs
 from ..obs.record import get_recorder
 from ..obs.trace import ConvergenceTrace
-from .paths import FlowPaths
+from .paths import _MXU_LANES, FlowPaths
 
 __all__ = ["FluidResult", "SaturationResult", "Certificate",
            "CertifiedResult", "evaluate_load", "saturation_throughput",
@@ -380,7 +380,10 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     a padded per-edge gather matrix in the common case (XLA:CPU serializes
     scatter-adds, so the dense gather + row-sum is ~5x faster per
     Frank-Wolfe iteration at ~1e-4 relative float32 rounding), or plain
-    scatter-add for pathologically skewed incidence counts.  The
+    scatter-add for pathologically skewed incidence counts.  With the
+    "mxu" kind, float32 loads are one matmul instead
+    (`_mxu_link_loads`; its one-hot of the edge ids' high parts is built
+    once per call, before the loops); float64 loads gather as "pad".  The
     optimization barriers keep XLA from fusing the weight / delay tables
     into their consuming gathers, which would serialize them; `barrier=False`
     drops them.  The vmapped batch solvers pass `barrier=False`.  JAX can
@@ -389,7 +392,7 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     measurement yet says the barriers help there.
 
     Device scopes (`jax.named_scope`, in the op_names of a profile):
-    `fluid.loads` (`loads`, either incidence path), `fluid.cost`
+    `fluid.loads` (`loads`, every incidence path), `fluid.cost`
     (`cost_of`, around `minplus.path_costs`), `fluid.target`
     (`target_of`), `fluid.line_search` (the exact line search) and
     `fluid.certify` (the gap and bracket at each chunk boundary).
@@ -435,10 +438,18 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     def _barrier(x):
         return jax.lax.optimization_barrier(x) if barrier else x
 
+    mxu = loads_kind == "mxu" and jnp.dtype(dtype) == jnp.float32
+    if mxu:
+        with jax.named_scope("fluid.loads"):
+            hi_onehot, lo_onehot = _mxu_factors(eidx, num_links)
+
     @jax.named_scope("fluid.loads")
     def loads(split, demand):
         w = (split * demand[:, None]).reshape(-1)  # [F*K]
-        if loads_kind == "pad":
+        if mxu:
+            return _mxu_link_loads(w, hi_onehot, lo_onehot, eidx.shape[2],
+                                   num_links)
+        if loads_kind in ("pad", "mxu"):
             (inc,) = loads_arrays
             w = _barrier(jnp.concatenate([w, jnp.zeros(1, w.dtype)]))
             return w[inc].sum(axis=1)  # [E]
@@ -729,6 +740,57 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     return _FWPieces(init, equilibrate, loads, cost_of, fw_target,
                      target_of, gap_of, cert_equilibrate,
                      equilibrate_traced)
+
+
+def _mxu_factors(eidx, num_links: int):
+    """One-hot factors of the edge ids for `_mxu_link_loads`: id =
+    `_MXU_LANES` * hi + lo, as [M, ceil((E+1)/lanes)] and [M, lanes]
+    bfloat16 (0 and 1 are exact), M = F * K * L.  The pad id `num_links`
+    falls in the tail the loads slice off."""
+    flat = eidx.reshape(-1)
+    n_hi = -(-(num_links + 1) // _MXU_LANES)
+    return (jax.nn.one_hot(flat // _MXU_LANES, n_hi, dtype=jnp.bfloat16),
+            jax.nn.one_hot(flat % _MXU_LANES, _MXU_LANES,
+                           dtype=jnp.bfloat16))
+
+
+def _bf16_head(x):
+    """float32 `x` cut to its top 8 significand bits (sign, exponent, 7
+    stored bits): exact in bfloat16.  A bit mask, not a rounding convert:
+    XLA may keep a float32 -> bfloat16 -> float32 round trip in float32
+    (excess precision), which would leave no remainder to split off."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(0xFFFF0000)
+    return jax.lax.bitcast_convert_type(bits, x.dtype)
+
+
+def _bf16_parts(x):
+    """[..., 3] bfloat16 parts of float32 `x` whose float32 sum is `x`
+    exactly: the top 8 significand bits, the next 8, and what is left,
+    which holds at most 8 (24 in all)."""
+    h1 = _bf16_head(x)
+    r1 = x - h1
+    h2 = _bf16_head(r1)
+    return jnp.stack([h1, h2, r1 - h2], axis=-1).astype(jnp.bfloat16)
+
+
+def _mxu_link_loads(w, hi_onehot, lo_onehot, path_len: int, num_links: int):
+    """rho [E] from candidate weights w [F*K] as one MXU contraction.
+
+    rho[128 * hi + lo] = sum over path-link slots m with that edge id of
+    w[m // L]: one-hot(hi)^T [n_hi, M] times one-hot(lo) scaled by w,
+    [M, lanes].  Each float32 weight is three bfloat16 parts that add up
+    to it exactly (`_bf16_parts`), so every product is exact; stacked as
+    3 * lanes columns and accumulated in float32, each edge's three part
+    sums are added at the end, and rounding stays proportional to each
+    edge's own load, as in the padded gather."""
+    wm = jnp.broadcast_to(w[:, None], (w.shape[0], path_len)).reshape(-1)
+    parts = _bf16_parts(wm)  # [M, 3]
+    rhs = (parts[:, :, None] * lo_onehot[:, None, :]).reshape(wm.shape[0], -1)
+    out = jax.lax.dot_general(hi_onehot, rhs, (((0,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    out = out.reshape(hi_onehot.shape[1], 3, _MXU_LANES)
+    rho = (out[:, 0] + out[:, 1]) + out[:, 2]
+    return rho.reshape(-1)[:num_links]
 
 
 def _max_util(rho, num_links: int):

@@ -72,6 +72,16 @@ __all__ = ["DirectedEdges", "FlowPaths", "build_directed_edges",
 # serialized scatter path and run ~5x slower per Frank-Wolfe step.
 _INC_PAD_MAX_ENTRIES = 32_000_000
 
+# Link loads as one MXU contraction (fluid.py `_mxu_link_loads`): each edge
+# id splits as 128 * hi + lo, and the loads are one-hot(hi)^T times the
+# weighted one-hot(lo).  XLA's TPU gather pays per index, the matmul per
+# byte, so on these platforms a padded incidence whose [M, ceil((E+1)/128)]
+# bfloat16 one-hot(hi) fits `_MXU_LOADS_MAX_BYTES` becomes ("mxu", inc);
+# M = F * K * L is the number of path-link slots.
+_MXU_LANES = 128
+_MXU_LOADS_MAX_BYTES = 64 * 2 ** 20
+_MXU_LOADS_PLATFORMS = ("tpu",)
+
 
 @dataclass
 class DirectedEdges:
@@ -189,6 +199,11 @@ class FlowPaths:
                     weights from a padded per-edge incidence matrix (pad
                     index F*K -> appended zero weight); dense gathers beat
                     scatter-add ~5x on XLA:CPU and accumulate edge-locally.
+                    ("mxu", inc) is the same on a platform in
+                    `_MXU_LOADS_PLATFORMS` when the one-hot operand fits
+                    `_MXU_LOADS_MAX_BYTES`: float32 loads are then one
+                    matmul over one-hot factors of the edge id (float64
+                    loads still gather from `inc`).
                     ("scatter",) falls back to plain scatter-add when padding
                     would blow up (pathologically skewed incidence counts --
                     those cases are small, so scatter speed doesn't matter,
@@ -198,15 +213,18 @@ class FlowPaths:
                     computes mean hops in-jit).
 
         The first call runs in a ``paths.incidence`` span (``repro.obs``):
-        the host work and the uploads, not their completion.
+        the host work and the uploads, not their completion; the span's
+        ``loads_kind`` attribute names the kind chosen.
         """
         if self._device is None:
-            with get_recorder().span("paths.incidence"):
+            with get_recorder().span("paths.incidence") as sp:
                 self._device = self._upload()
+                sp.set(loads_kind=self._device[1][0])
         return self._device
 
     def _upload(self) -> tuple:
         """Build the arrays `device_arrays` returns and upload them."""
+        import jax
         import jax.numpy as jnp
         f, k, l = self.edges.shape
         flat = self.edges.reshape(-1)
@@ -222,7 +240,10 @@ class FlowPaths:
             cols = np.concatenate([np.arange(c) for c in counts]) \
                 if nnz else np.zeros(0, dtype=np.int64)
             inc[e_of[order], cols] = fk[order]
-            loads_rep = ("pad", jnp.asarray(inc))
+            n_hi = -(-(self.num_links + 1) // _MXU_LANES)
+            mxu = (jax.default_backend() in _MXU_LOADS_PLATFORMS
+                   and f * k * l * n_hi * 2 <= _MXU_LOADS_MAX_BYTES)
+            loads_rep = ("mxu" if mxu else "pad", jnp.asarray(inc))
         else:
             loads_rep = ("scatter",)
         eidx = np.where(self.edges >= 0, self.edges, self.num_links)

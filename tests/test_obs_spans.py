@@ -158,6 +158,33 @@ def test_host_layers_open_their_spans():
                    "repro.core.routing._dest_device_fn.fn"]
 
 
+@pytest.mark.parametrize("kind", ["pad", "mxu", "scatter"])
+def test_incidence_span_names_the_loads_kind(monkeypatch, kind):
+    """The `paths.incidence` span names the link-load kind its upload
+    chose, once however often the arrays are asked for."""
+    from repro.simulation import paths as paths_mod
+    pf = build_polarfly(7)
+    rt = build_routing(pf.graph, pf)
+    src = np.arange(1, pf.graph.n, dtype=np.int32)
+    if kind == "scatter":
+        # every router sends to router 0: too skewed to pad with no cap
+        monkeypatch.setattr(paths_mod, "_INC_PAD_MAX_ENTRIES", 0)
+        dst = np.zeros(len(src), np.int32)
+    else:
+        dst = np.roll(src, 1)
+    if kind == "mxu":
+        monkeypatch.setattr(paths_mod, "_MXU_LOADS_PLATFORMS", ("cpu",))
+    fp = build_flow_paths(rt, TrafficPattern(
+        "perm", src, dst, np.ones(len(src), np.float32), 1), "min")
+    rec = Recorder()
+    with recording(rec):
+        fp.device_arrays()
+        fp.device_arrays()
+    (ev,) = [e for e in rec.events() if e["name"] == "paths.incidence"]
+    assert ev["args"] == {"loads_kind": kind}
+    assert fp.device_arrays()[1][0] == kind
+
+
 def test_packet_workload_span():
     pf = build_polarfly(7)
     rt = build_routing(pf.graph, pf)

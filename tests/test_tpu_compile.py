@@ -71,19 +71,39 @@ def test_path_costs_compiles_for_v5e_at_pf79(one_chip):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
-def test_certified_saturation_compiles_for_v5e(one_chip):
-    """The certified UGAL bisection (conjugate Frank-Wolfe, line search,
-    gap bracket) at PF(7)-like shapes, padded-incidence link loads."""
-    f, k, l, e, w = 56, 8, 4, 456, 12
+def _certified_saturation(one_chip, kind, f, k, l, e, w):
+    """`_certified_saturation` compiled for one chip: UGAL, 3 probes."""
     args = (_on(one_chip, (f, k, l), jnp.int32),
-            (_on(one_chip, (e, w), jnp.int32),), "pad",
+            (_on(one_chip, (e, w), jnp.int32),), kind,
             _on(one_chip, (f, k), jnp.bool_),
             _on(one_chip, (f, k), jnp.bool_),
             _on(one_chip, (f,), jnp.int32),
             _on(one_chip, (f,), jnp.float32))
-    compiled = fluid._certified_saturation.lower(
+    return fluid._certified_saturation.lower(
         *args, e, "ugal", 0.05, 256, 3, "float32", 0).compile()
+
+
+def test_certified_saturation_compiles_for_v5e(one_chip):
+    """The certified UGAL bisection (conjugate Frank-Wolfe, line search,
+    gap bracket) at PF(7)-like shapes, padded-incidence link loads."""
+    compiled = _certified_saturation(one_chip, "pad", 56, 8, 4, 456, 12)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("f,k,l,e,w", [
+    (56, 8, 4, 456, 12),          # PF(7)-like, as above
+    (993, 11, 4, 31_744, 9)])     # PF(31) UGAL, 1 minimal + 10 Valiant
+def test_certified_saturation_mxu_loads_compile_for_v5e(one_chip, f, k, l,
+                                                        e, w):
+    """The same bisection with ("mxu", inc) link loads: XLA's own matmul
+    under `fluid.loads` (no custom kernel), and no gather left there."""
+    compiled = _certified_saturation(one_chip, "mxu", f, k, l, e, w)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    loads_ops = [ln for ln in text.splitlines() if "fluid.loads/" in ln]
+    assert any("convolution(" in ln for ln in loads_ops)
+    assert not any("gather(" in ln for ln in loads_ops)
 
 
 def test_packet_scan_compiles_for_v5e(one_chip):
