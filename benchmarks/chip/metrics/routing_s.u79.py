@@ -1,0 +1,11 @@
+"""`routing_s.u79`: `routing_s`'s reading, in `pf79_ugal.sat`."""
+
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+
+import harness  # noqa: E402
+
+read = harness.load_module("metrics", "routing_s").read

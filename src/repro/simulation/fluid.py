@@ -383,7 +383,9 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     scatter-add for pathologically skewed incidence counts.  With the
     "mxu" kind, float32 loads are one matmul instead
     (`_mxu_link_loads`; its one-hot of the edge ids' high parts is built
-    once per call, before the loops); float64 loads gather as "pad".  The
+    once per call, before the loops), and with "mxu_tiles" one matmul per
+    tile of edge ids, over the candidate weights gathered into the tiles'
+    edge order; float64 loads gather as "pad" with either.  The
     optimization barriers keep XLA from fusing the weight / delay tables
     into their consuming gathers, which would serialize them; `barrier=False`
     drops them.  The vmapped batch solvers pass `barrier=False`.  JAX can
@@ -438,21 +440,27 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     def _barrier(x):
         return jax.lax.optimization_barrier(x) if barrier else x
 
-    mxu = loads_kind == "mxu" and jnp.dtype(dtype) == jnp.float32
+    mxu = (loads_kind in ("mxu", "mxu_tiles")
+           and jnp.dtype(dtype) == jnp.float32)
     if mxu:
         with jax.named_scope("fluid.loads"):
-            hi_onehot, lo_onehot = _mxu_factors(eidx, num_links)
+            hi_onehot, lo_onehot = _mxu_factors(
+                eidx.reshape(-1) if loads_kind == "mxu" else loads_arrays[2],
+                num_links)
 
     @jax.named_scope("fluid.loads")
     def loads(split, demand):
         w = (split * demand[:, None]).reshape(-1)  # [F*K]
-        if mxu:
-            return _mxu_link_loads(w, hi_onehot, lo_onehot, eidx.shape[2],
+        if mxu and loads_kind == "mxu":
+            wm = jnp.broadcast_to(w[:, None], (w.shape[0], eidx.shape[2]))
+            return _mxu_link_loads(wm.reshape(-1), hi_onehot, lo_onehot,
                                    num_links)
-        if loads_kind in ("pad", "mxu"):
-            (inc,) = loads_arrays
+        if loads_kind in ("pad", "mxu", "mxu_tiles"):
             w = _barrier(jnp.concatenate([w, jnp.zeros(1, w.dtype)]))
-            return w[inc].sum(axis=1)  # [E]
+            if mxu:  # the tiles' candidate weights, in edge order
+                return _mxu_link_loads(w[loads_arrays[1]], hi_onehot,
+                                       lo_onehot, num_links)
+            return w[loads_arrays[0]].sum(axis=1)  # [E]
         # "scatter" fallback for pathologically skewed incidence counts:
         # slower, but rounding stays proportional to each edge's own load
         w3 = w.reshape(eidx.shape[0], eidx.shape[1], 1) \
@@ -742,15 +750,18 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
                      equilibrate_traced)
 
 
-def _mxu_factors(eidx, num_links: int):
-    """One-hot factors of the edge ids for `_mxu_link_loads`: id =
-    `_MXU_LANES` * hi + lo, as [M, ceil((E+1)/lanes)] and [M, lanes]
-    bfloat16 (0 and 1 are exact), M = F * K * L.  The pad id `num_links`
-    falls in the tail the loads slice off."""
-    flat = eidx.reshape(-1)
+def _mxu_factors(slot_ids, num_links: int):
+    """One-hot factors of the slots' edge ids for `_mxu_link_loads`: id =
+    `_MXU_LANES` * hi + lo.  `slot_ids` is [M] edge ids ("mxu": every
+    path-link slot, M = F * K * L) or [T, S] ids within T tiles of
+    ceil(n_hi / T) hi rows each ("mxu_tiles"); the factors are
+    [..., rows] and [..., lanes] bfloat16 (0 and 1 are exact).  The pad id
+    `num_links` falls in the tail the loads slice off."""
     n_hi = -(-(num_links + 1) // _MXU_LANES)
-    return (jax.nn.one_hot(flat // _MXU_LANES, n_hi, dtype=jnp.bfloat16),
-            jax.nn.one_hot(flat % _MXU_LANES, _MXU_LANES,
+    tiles = slot_ids.shape[0] if slot_ids.ndim == 2 else 1
+    return (jax.nn.one_hot(slot_ids // _MXU_LANES, -(-n_hi // tiles),
+                           dtype=jnp.bfloat16),
+            jax.nn.one_hot(slot_ids % _MXU_LANES, _MXU_LANES,
                            dtype=jnp.bfloat16))
 
 
@@ -773,22 +784,26 @@ def _bf16_parts(x):
     return jnp.stack([h1, h2, r1 - h2], axis=-1).astype(jnp.bfloat16)
 
 
-def _mxu_link_loads(w, hi_onehot, lo_onehot, path_len: int, num_links: int):
-    """rho [E] from candidate weights w [F*K] as one MXU contraction.
+def _mxu_link_loads(ws, hi_onehot, lo_onehot, num_links: int):
+    """rho [E] from the slots' weights `ws` ([M], or [T, S] by tile) as MXU
+    contractions, one per tile.
 
-    rho[128 * hi + lo] = sum over path-link slots m with that edge id of
-    w[m // L]: one-hot(hi)^T [n_hi, M] times one-hot(lo) scaled by w,
-    [M, lanes].  Each float32 weight is three bfloat16 parts that add up
-    to it exactly (`_bf16_parts`), so every product is exact; stacked as
-    3 * lanes columns and accumulated in float32, each edge's three part
-    sums are added at the end, and rounding stays proportional to each
-    edge's own load, as in the padded gather."""
-    wm = jnp.broadcast_to(w[:, None], (w.shape[0], path_len)).reshape(-1)
-    parts = _bf16_parts(wm)  # [M, 3]
-    rhs = (parts[:, :, None] * lo_onehot[:, None, :]).reshape(wm.shape[0], -1)
-    out = jax.lax.dot_general(hi_onehot, rhs, (((0,), (0,)), ((), ())),
+    rho[128 * hi + lo] = sum over slots with that edge id of their weight:
+    one-hot(hi)^T [rows, S] times one-hot(lo) scaled by the weights,
+    [S, lanes], with tile t holding hi rows [t * rows, (t + 1) * rows).
+    Each float32 weight is three bfloat16 parts that add up to it exactly
+    (`_bf16_parts`), so every product is exact; stacked as 3 * lanes
+    columns and accumulated in float32, each edge's three part sums are
+    added at the end, and rounding stays proportional to each edge's own
+    load, as in the padded gather."""
+    parts = _bf16_parts(ws)  # [..., S, 3]
+    rhs = (parts[..., None] * lo_onehot[..., None, :]).reshape(
+        *ws.shape, -1)
+    c = ws.ndim - 1  # the slot axis; any axis before it is the tile's
+    out = jax.lax.dot_general(hi_onehot, rhs,
+                              (((c,), (c,)), (tuple(range(c)),) * 2),
                               preferred_element_type=jnp.float32)
-    out = out.reshape(hi_onehot.shape[1], 3, _MXU_LANES)
+    out = out.reshape(-1, 3, _MXU_LANES)
     rho = (out[:, 0] + out[:, 1]) + out[:, 2]
     return rho.reshape(-1)[:num_links]
 
@@ -1042,7 +1057,13 @@ def _certified_saturation(eidx, loads_arrays, loads_kind, valid, is_min,
 
     Probe sequence mirrors `_saturation_batch` (offered = 1.0 first, then
     `probes` bisection steps over [0, 1], each warm-started from the
-    previous probe's split at `_WARM_T0`), but every probe runs
+    previous probe's split at `_WARM_T0`), except that once a probe has
+    been judged feasible every later probe starts from the split of the
+    last feasible one: an infeasible probe's iterate piles load past the
+    delay cap, where the cost is flat, and a lower load started there can
+    still read max utilization > 1 when its budget runs out although its
+    equilibrium is feasible (PF(79) UGAL lost a whole bisection cell that
+    way).  Every probe runs
     `cert_equilibrate` with `decide_at=1.0`: it stops as soon as the gap's
     per-link utilization bracket certifies the probe's feasibility either
     way -- the uncertified engine's fixed per-probe budgets become
@@ -1076,13 +1097,16 @@ def _certified_saturation(eidx, loads_arrays, loads_kind, valid, is_min,
     hi_c = one
     trs = [tr]
     brs = [(one, (mu1 <= 1.0).astype(dt), lo, hi)]
+    warm, found = split, mu1 <= 1.0
     for _ in range(probes):
         mid = 0.5 * (lo + hi)
         dd = d1 * mid
         split, rho, gap, mu_lb, mu_ub, it, ok, tr = fw.cert_equilibrate(
-            split, dd, max_iters, util_tol, t0=_WARM_T0, decide_at=1.0,
+            warm, dd, max_iters, util_tol, t0=_WARM_T0, decide_at=1.0,
             trace_cap=trace_cap)
         feasible = _max_util(rho, num_links) <= 1.0
+        warm = jnp.where(feasible | ~found, split, warm)
+        found = found | feasible
         lo = jnp.where(feasible, mid, lo)
         hi = jnp.where(feasible, hi, mid)
         lo_c = jnp.where(mu_ub <= 1.0, jnp.maximum(lo_c, mid), lo_c)

@@ -72,15 +72,47 @@ __all__ = ["DirectedEdges", "FlowPaths", "build_directed_edges",
 # serialized scatter path and run ~5x slower per Frank-Wolfe step.
 _INC_PAD_MAX_ENTRIES = 32_000_000
 
-# Link loads as one MXU contraction (fluid.py `_mxu_link_loads`): each edge
+# Link loads as MXU contractions (fluid.py `_mxu_link_loads`): each edge
 # id splits as 128 * hi + lo, and the loads are one-hot(hi)^T times the
 # weighted one-hot(lo).  XLA's TPU gather pays per index, the matmul per
-# byte, so on these platforms a padded incidence whose [M, ceil((E+1)/128)]
-# bfloat16 one-hot(hi) fits `_MXU_LOADS_MAX_BYTES` becomes ("mxu", inc);
-# M = F * K * L is the number of path-link slots.
+# byte, so on these platforms a padded incidence becomes ("mxu", inc) where
+# the [M, ceil((E+1)/128)] bfloat16 one-hot(hi) of all M = F * K * L
+# path-link slots fits `_MXU_LOADS_MAX_BYTES`, and ("mxu_tiles", ...)
+# where it does not: the slots sorted by edge id and cut into tiles of
+# consecutive hi ranges, each tile's one-hot within `_MXU_TILE_MAX_BYTES`.
+# A tiled call adds a gather of the weights into edge order; its
+# contractions' work is slots x rows, so smaller tiles do less of it
+# (PF(79) UGAL on a v5e: 2.88 / 2.44 ms a loads call at 64 / 4 MiB tiles,
+# 2.51 at 1 MiB; 4.56 ms untiled).
 _MXU_LANES = 128
 _MXU_LOADS_MAX_BYTES = 64 * 2 ** 20
+_MXU_TILE_MAX_BYTES = 4 * 2 ** 20
 _MXU_LOADS_PLATFORMS = ("tpu",)
+
+
+def _mxu_tiles(edge: np.ndarray, fk: np.ndarray, n_hi: int, pad: int):
+    """The ("mxu_tiles", ...) arrays: the path-link slots, sorted by edge id
+    (`edge`, with each slot's candidate id `fk`), cut into the fewest T
+    tiles of ceil(n_hi / T) consecutive hi rows each whose bfloat16
+    one-hot(hi), [S, rows] with S the fullest tile's slot count, fits
+    `_MXU_TILE_MAX_BYTES` (down to one row a tile).  Returns [T, S]
+    candidate ids (`pad` on a tile's unused slots) and [T, S] edge ids less
+    the tile's first (0 there)."""
+    per_hi = np.bincount(edge // _MXU_LANES, minlength=n_hi)
+    for t in range(1, n_hi + 1):
+        rows = -(-n_hi // t)
+        per_tile = np.bincount(np.arange(n_hi) // rows, weights=per_hi,
+                               minlength=t)
+        s = int(per_tile.max())
+        if s * rows * 2 <= _MXU_TILE_MAX_BYTES or rows == 1:
+            break
+    tile = edge // (rows * _MXU_LANES)
+    pos = np.arange(len(edge)) - np.searchsorted(tile, tile)
+    slot_fk = np.full((t, s), pad, dtype=np.int32)
+    slot_ids = np.zeros((t, s), dtype=np.int32)
+    slot_fk[tile, pos] = fk
+    slot_ids[tile, pos] = edge - tile * rows * _MXU_LANES
+    return slot_fk, slot_ids
 
 
 @dataclass
@@ -204,6 +236,11 @@ class FlowPaths:
                     `_MXU_LOADS_MAX_BYTES`: float32 loads are then one
                     matmul over one-hot factors of the edge id (float64
                     loads still gather from `inc`).
+                    ("mxu_tiles", inc, slot_fk, slot_ids) where it does
+                    not: float32 loads are one such matmul per tile of
+                    edge ids (`_mxu_tiles`) over the candidate weights
+                    gathered into edge order (float64 loads gather from
+                    `inc`).
                     ("scatter",) falls back to plain scatter-add when padding
                     would blow up (pathologically skewed incidence counts --
                     those cases are small, so scatter speed doesn't matter,
@@ -237,13 +274,19 @@ class FlowPaths:
         w_max = int(counts.max()) if nnz else 0
         if self.num_links * w_max <= max(4 * nnz, _INC_PAD_MAX_ENTRIES):
             inc = np.full((self.num_links, w_max), f * k, dtype=np.int32)
-            cols = np.concatenate([np.arange(c) for c in counts]) \
-                if nnz else np.zeros(0, dtype=np.int64)
+            # each slot's rank among the slots of its edge
+            cols = np.arange(nnz) - np.repeat(np.cumsum(counts) - counts,
+                                              counts)
             inc[e_of[order], cols] = fk[order]
+            kind, tiles = "pad", ()
             n_hi = -(-(self.num_links + 1) // _MXU_LANES)
-            mxu = (jax.default_backend() in _MXU_LOADS_PLATFORMS
-                   and f * k * l * n_hi * 2 <= _MXU_LOADS_MAX_BYTES)
-            loads_rep = ("mxu" if mxu else "pad", jnp.asarray(inc))
+            if jax.default_backend() in _MXU_LOADS_PLATFORMS:
+                if f * k * l * n_hi * 2 <= _MXU_LOADS_MAX_BYTES:
+                    kind = "mxu"
+                else:
+                    kind = "mxu_tiles"
+                    tiles = _mxu_tiles(e_of[order], fk[order], n_hi, f * k)
+            loads_rep = (kind, jnp.asarray(inc), *map(jnp.asarray, tiles))
         else:
             loads_rep = ("scatter",)
         eidx = np.where(self.edges >= 0, self.edges, self.num_links)

@@ -71,10 +71,13 @@ def test_path_costs_compiles_for_v5e_at_pf79(one_chip):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
-def _certified_saturation(one_chip, kind, f, k, l, e, w):
-    """`_certified_saturation` compiled for one chip: UGAL, 3 probes."""
+def _certified_saturation(one_chip, kind, f, k, l, e, w, tiles=()):
+    """`_certified_saturation` compiled for one chip: UGAL, 3 probes;
+    `tiles` (T, S) gives ("mxu_tiles", ...)'s two [T, S] arrays."""
     args = (_on(one_chip, (f, k, l), jnp.int32),
-            (_on(one_chip, (e, w), jnp.int32),), kind,
+            (_on(one_chip, (e, w), jnp.int32),
+             *[_on(one_chip, tiles, jnp.int32)] * (2 if tiles else 0)),
+            kind,
             _on(one_chip, (f, k), jnp.bool_),
             _on(one_chip, (f, k), jnp.bool_),
             _on(one_chip, (f,), jnp.int32),
@@ -104,6 +107,20 @@ def test_certified_saturation_mxu_loads_compile_for_v5e(one_chip, f, k, l,
     loads_ops = [ln for ln in text.splitlines() if "fluid.loads/" in ln]
     assert any("convolution(" in ln for ln in loads_ops)
     assert not any("gather(" in ln for ln in loads_ops)
+
+
+def test_certified_saturation_mxu_tiles_compile_for_v5e_at_pf79(one_chip):
+    """The bisection at `pf79_ugal.sat`'s shapes (F = N = 6,321 flows, one
+    a router) with ("mxu_tiles", ...) loads, the tiles its deployment
+    takes on the chip (23 of 11,686 slots): XLA's own matmuls under
+    `fluid.loads`, within a tenth of the chip's memory."""
+    compiled = _certified_saturation(one_chip, "mxu_tiles", 6321, PF79_K,
+                                     PF79_L, PF79_E, 6, (23, 11_686))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert any("convolution(" in ln for ln in text.splitlines()
+               if "fluid.loads/" in ln)
 
 
 def test_packet_scan_compiles_for_v5e(one_chip):
