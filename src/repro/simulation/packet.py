@@ -47,11 +47,12 @@ Engines:
 * `simulate_packets` -- the scale engine: per-link queues as one dense
   ``[E + 1, Q]`` id matrix (row E is the arbitration dump row), a
   `lax.scan` over cycles, sort-based arbitration (stable argsort by
-  target + segmented ranks -- no ``.at[].add()`` scatter on the cycle
-  path), gather-only routing lookups, no host syncs inside jit, and no
-  ``[n, n]`` allocation anywhere.  `simulate_packets_batch` vmaps the
-  same scan over a stack of same-shape workloads (e.g. seed replicas)
-  in one dispatch.
+  target, then ranks within each target's run from a running max of the
+  run starts and per-link counts from each run's last rank -- no search
+  and no ``.at[].add()`` scatter on the cycle path), gather-only routing
+  lookups, no host syncs inside jit, and no ``[n, n]`` allocation
+  anywhere.  `simulate_packets_batch` vmaps the same scan over a stack
+  of same-shape workloads (e.g. seed replicas) in one dispatch.
 
 Scenarios (`make_workload` / `build_failure_workload`): steady uniform /
 tornado / any `TrafficPattern` load, on-off bursts (`BurstSchedule`,
@@ -681,6 +682,23 @@ def _arrays(wl: PacketWorkload, record: np.ndarray) -> tuple:
             jnp.asarray(np.int32(p)))
 
 
+def _segment_ranks(st: jax.Array, e_num: int) -> tuple:
+    """Arbitration ranks from sorted targets `st` (values in [0, e_num],
+    e_num the dump target): each lane's rank within its run of equal
+    targets, and each link's candidate count ([e_num]).  A rank is the
+    position less the run's first position, a running max of the run
+    starts; a count is the last lane's rank + 1, set at its link (the
+    dump lane e_num takes the rest).  No search, no scatter-add."""
+    pos = jnp.arange(st.shape[0], dtype=jnp.int32)
+    step = st[1:] != st[:-1]
+    first = jnp.concatenate([jnp.ones(1, bool), step])
+    last = jnp.concatenate([step, jnp.ones(1, bool)])
+    rank = pos - jax.lax.cummax(jnp.where(first, pos, 0))
+    cnt = jnp.zeros(e_num + 1, jnp.int32).at[
+        jnp.where(last & (st < e_num), st, e_num)].set(rank + 1)
+    return rank, cnt[:e_num]
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("e_num", "size", "capacity", "adaptive", "gated",
@@ -693,12 +711,13 @@ def _run_batched(eidx, hops, n_valid, pkt_flow, pkt_t, pkt_cand, src_off,
     transform, scan epoch 1.  State is dense int32 arrays only -- queues
     [E + 1, Q] (row E absorbs rejected scatter lanes), per-link occ/serve,
     per-packet hop/chosen/epoch/outcome -- and every per-cycle update is
-    gathers, one stable argsort (arbitration order), segmented ranks via
-    searchsorted, and unique-index `.at[].set` scatters.  No host syncs,
-    no [n, n] anything, no scatter-add.  Device scopes of a cycle:
+    gathers, one stable argsort (arbitration order), segmented ranks and
+    per-link counts from the sorted targets (`_segment_ranks`), and
+    unique-index `.at[].set` scatters.  No host syncs, no searches, no
+    [n, n] anything, no scatter-add.  Device scopes of a cycle:
     `packet.route` (the per-hop and injection gathers),
-    `packet.arbitrate` (the stable argsort and the segmented-rank
-    searchsorteds), `packet.queues` (the `.at[].set` scatters)."""
+    `packet.arbitrate` (the stable argsort, the segmented ranks and
+    counts), `packet.queues` (the `.at[].set` scatters)."""
     q_cap = capacity
     p_pad = pkt_flow.shape[0] - 1  # static pad slot == P
     gate = _gate_occ(q_cap)
@@ -742,15 +761,9 @@ def _run_batched(eidx, hops, n_valid, pkt_flow, pkt_t, pkt_cand, src_off,
                 free = q_cap - occ
                 order = jnp.argsort(tgt, stable=True)
                 st = tgt[order]
-                rank = (jnp.arange(e_num, dtype=jnp.int32)
-                        - jnp.searchsorted(st, st, side="left"
-                                           ).astype(jnp.int32))
+                rank, cnt_cand = _segment_ranks(st, e_num)
                 free_pad = jnp.concatenate([free, jnp.zeros(1, jnp.int32)])
                 acc_s = (st < e_num) & (rank < free_pad[st])
-                eids = jnp.arange(e_num, dtype=jnp.int32)
-                cnt_cand = (jnp.searchsorted(st, eids, side="right")
-                            - jnp.searchsorted(st, eids, side="left")
-                            ).astype(jnp.int32)
                 acc_cnt = jnp.minimum(cnt_cand, free)
                 acc_cnt_pad = jnp.concatenate([acc_cnt,
                                                jnp.zeros(1, jnp.int32)])

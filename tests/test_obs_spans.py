@@ -241,7 +241,7 @@ def packet_op_names(pf7_ugal):
 
 
 @pytest.mark.parametrize("scope,op", [
-    ("packet.arbitrate", "sort"), ("packet.arbitrate", "searchsorted"),
+    ("packet.arbitrate", "sort"), ("packet.arbitrate", "scatter"),
     ("packet.queues", "scatter"), ("packet.route", "gather")])
 def test_packet_cycle_scopes_in_the_scan(packet_op_names, scope, op):
     assert any(f"{scope}/" in n and op in n.split(f"{scope}/", 1)[1]

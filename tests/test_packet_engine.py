@@ -7,6 +7,7 @@ failure-transient drop semantics."""
 
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
@@ -19,6 +20,7 @@ from repro.simulation import (BurstSchedule, build_failure_workload,
                               packet_peak_bytes, simulate_packets,
                               simulate_packets_batch,
                               simulate_packets_reference)
+from repro.simulation.packet import _segment_ranks
 
 MODES = ("min", "valiant", "ugal")
 
@@ -97,6 +99,40 @@ def test_engines_bit_identical(graph, mode, damaged):
     r_bat = simulate_packets(wl)
     assert r_ref.num_delivered > 100  # the comparison is non-vacuous
     _assert_identical(wl, r_ref, r_bat)
+
+
+def _sorted_targets(rng, e, mix):
+    """Sorted arbitration targets of e lanes in [0, e] (e is the dump)."""
+    if mix == "all_pad":
+        t = np.full(e, e)
+    elif mix == "no_pad":
+        t = rng.integers(0, e, e)
+    elif mix == "one_link":
+        t = np.full(e, rng.integers(0, e))
+    else:
+        t = np.where(rng.random(e) < rng.random(), e, rng.integers(0, e, e))
+    return np.sort(t).astype(np.int32)
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["jit", "vmap"])
+@pytest.mark.parametrize("mix", ["all_pad", "no_pad", "one_link", "random"])
+@pytest.mark.parametrize("e", [1, 7, 456, 31_744])
+def test_segment_ranks_match_searchsorted(e, mix, batched):
+    """Arbitration ranks within each target's run, and candidates per
+    link, equal the binary-search definitions on the same sorted
+    targets."""
+    rng = np.random.default_rng(e)
+    sts = np.stack([_sorted_targets(rng, e, mix) for _ in range(3)])
+    fn = jax.jit(_segment_ranks, static_argnums=1)
+    if batched:
+        rank, cnt = jax.vmap(fn, in_axes=(0, None))(sts, e)
+    else:
+        rank, cnt = zip(*(fn(s, e) for s in sts))
+    eids = np.arange(e)
+    for s, r, c in zip(sts, np.asarray(rank), np.asarray(cnt)):
+        np.testing.assert_array_equal(r, eids - np.searchsorted(s, s))
+        np.testing.assert_array_equal(
+            c, np.searchsorted(s, eids, "right") - np.searchsorted(s, eids))
 
 
 def test_zero_load_latency_is_hops_times_size():

@@ -13,6 +13,7 @@ every worker imports this file.  All such compiles stay in this one file,
 so one worker holds the library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,9 +124,27 @@ def test_certified_saturation_mxu_tiles_compile_for_v5e_at_pf79(one_chip):
                if "fluid.loads/" in ln)
 
 
+def _whiles_in_loops(hlo: str) -> list:
+    """The `while` ops of a compiled HLO module that the body of another
+    `while` reaches, through any computation it calls."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%(\S+) [^\n]*\{\n(.*?)^\}$", hlo,
+                            re.M | re.S))
+    inner, seen = [], set()
+    stack = re.findall(r"\bwhile\(.*?\bbody=%([\w.\-]+)", hlo)
+    while stack:
+        name = stack.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        inner += re.findall(r"%(\S+) = .*\bwhile\(", comps[name])
+        stack += re.findall(r"%([\w.\-]+)", comps[name])
+    return inner
+
+
 def test_packet_scan_compiles_for_v5e(one_chip):
     """The packet engine's scan (sort-based arbitration, UGAL injection
-    choice) at small shapes."""
+    choice) at small shapes.  A cycle holds no loop of its own: a binary
+    search (`jnp.searchsorted`'s default lowering) would be one."""
     f, k, l1, p, n, e = 56, 8, 5, 4000, 57, 456
     i32 = jnp.int32
     args = (_on(one_chip, (2, f, k, l1), i32), _on(one_chip, (2, f, k), i32),
@@ -137,6 +156,9 @@ def test_packet_scan_compiles_for_v5e(one_chip):
         *args, e_num=e, size=4, capacity=32, adaptive=True, gated=False,
         seg0=100, seg1=0).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    text = compiled.as_text()
+    assert "while(" in text  # the scan over cycles
+    assert _whiles_in_loops(text) == []
 
 
 def test_sharded_dest_columns_compile_for_v5e_2x2(topo, one_chip):
