@@ -77,7 +77,7 @@ def _obs_noop_overhead():
     def raw():
         return float(_saturation_batch(
             eidx, loads_rep[1:], loads_rep[0], valid, is_min, first_edge,
-            demand, fp.num_links, fp.mode, ITERS, sched))
+            demand, fp.num_links, fp.mode, ITERS, sched)[0])
 
     def pub():
         return saturation_throughput(fp, tol=TOL, iters=ITERS,

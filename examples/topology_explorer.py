@@ -7,8 +7,8 @@ Under BENCH_SMOKE=1 the table shrinks to PF(7)/DF(4,2) and a reduced
 Frank-Wolfe budget, so the script runs in seconds (this is what CI
 executes).  Path construction and the fluid solver run on their default
 engines (`engine="auto"` / batched); the adaptive column also reports the
-solver's own truncation-error estimate (`SaturationResult.truncation_err`,
-see docs/benchmarks.md) so you can tell whether the iteration budget was
+solver's own truncation-error estimate (`truncation_error`, see
+docs/benchmarks.md) so you can tell whether the iteration budget was
 enough.
 """
 import os
@@ -17,7 +17,8 @@ from repro.core import topologies as tp
 from repro.core.metrics import bisection_fraction, resilience_sweep
 from repro.core.polarfly import build_polarfly
 from repro.core.routing import build_routing
-from repro.simulation import build_flow_paths, make_pattern, saturation_throughput
+from repro.simulation import (build_flow_paths, make_pattern,
+                              saturation_throughput, truncation_error)
 
 
 def main():
@@ -48,14 +49,14 @@ def main():
         adv = make_pattern("random_perm", rt, p=p, seed=0)
         s_uni = saturation_throughput(build_flow_paths(rt, uni, "min"), tol=0.02)
         s_adv = saturation_throughput(build_flow_paths(rt, adv, "min"), tol=0.02)
-        res_ug = saturation_throughput(
-            build_flow_paths(rt, adv, "ugal", k_candidates=10), tol=0.02,
-            iters=iters, return_info=True)
+        fp_ug = build_flow_paths(rt, adv, "ugal", k_candidates=10)
+        s_ug = saturation_throughput(fp_ug, tol=0.02, iters=iters)
+        fw_err = truncation_error(fp_ug, s_ug, iters)
         bis = bisection_fraction(g)
         res = resilience_sweep(g, [0.2], seed=0)[0].diameter
         print(f"{name:20s} {g.n:5d} {g.params.get('radix','?'):>5} "
-              f"{s_uni:9.3f} {s_adv:8.3f} {res_ug.saturation:9.3f} "
-              f"{res_ug.truncation_err:7.4f} {bis:7.3f} {res:12d}")
+              f"{s_uni:9.3f} {s_adv:8.3f} {s_ug:9.3f} "
+              f"{fw_err:7.4f} {bis:7.3f} {res:12d}")
 
 
 if __name__ == "__main__":

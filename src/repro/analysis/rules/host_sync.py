@@ -13,7 +13,7 @@ jitted by assignment -- ``g = jax.jit(f)``, including through wrappers
 whose first positional argument is the function, as in the blockwise
 executor's ``mapped = jax.jit(shard_map(per_device, ...))`` -- and the
 rule scans its whole body including nested defs (so the certified fluid
-entry points -- ``_certified_solve`` / ``_certified_saturation`` and the
+entry points -- ``_certified_batch`` / ``_certified_saturation`` and the
 closures they trace -- are in scope: a ``float(gap)`` there is a per-call
 device round-trip).  ``float()``/``int()`` are only flagged
 when their argument mentions a *traced* parameter (not listed in

@@ -20,38 +20,48 @@ Outputs: per-link utilization, accepted throughput (saturation = largest
 offered load with max utilization <= 1), and mean latency in cycles
 (1 cycle router pipeline per hop + queueing delay).
 
-Two solver engines share one Frank-Wolfe core (`_fw_pieces`):
+Every solve traces one Frank-Wolfe core (`_fw_pieces`) inside one of five
+jitted programs, one per question:
 
-  * ``engine="batched"`` (default) -- the whole load sweep runs inside a
-    single jit.  `latency_curve` vmaps the equilibrium over the vector of
-    offered loads, so a P-point sweep is one compiled call instead of P
-    re-entries (identical per-load math; only the XLA fusion barriers are
-    dropped, see `_fw_pieces`).  `saturation_throughput` runs its bisection
-    as an in-jit unrolled probe loop (ceil(log2(1/tol)) probes, the scalar
-    bisection's probe sequence), with each probe's Frank-Wolfe split
-    warm-started from the previous probe's equilibrium: the Wardrop fixed
-    point does not depend on the starting split, so warm probes re-converge
-    in a fraction of `iters` steps (`_probe_schedule`: iters/2 for the
-    first half-range jump, iters/4 for the next four, iters/8 for the
-    fine tail).
-  * ``engine="scalar"`` -- the original per-probe dispatch (one `_solve`
-    call per offered load, every probe cold-started from scratch); kept as
-    the executable reference, the same two-engine pattern the path
-    builders use (`build_flow_paths`).
+  * `_solve_batch` -- the cold-start equilibrium, vmapped over a vector
+    of offered loads: `latency_curve` (the whole sweep in one call) and
+    `evaluate_load` (a batch of one).
+  * `_saturation_batch` -- `saturation_throughput`'s default
+    ``engine="batched"``: the bisection as an in-jit unrolled probe loop
+    (ceil(log2(1/tol)) probes, the scalar bisection's probe sequence),
+    each probe's split warm-started from the previous probe's
+    equilibrium.  The Wardrop fixed point does not depend on the starting
+    split, so warm probes re-converge in a fraction of `iters` steps
+    (`_probe_schedule`: iters/2 for the first half-range jump, iters/4
+    for the next four, iters/8 for the fine tail).
+  * `_truncation_gap` -- `truncation_error`.
+  * `_certified_batch` -- `certify=True` on `latency_curve` and
+    `evaluate_load` (again a batch of one).
+  * `_certified_saturation` -- `certify=True` on `saturation_throughput`.
+
+``trace=True`` is a static argument of the same programs, not a program of
+its own: the traced step computes the same split and adds the samples.
+``engine="scalar"`` on `saturation_throughput` / `latency_curve` is the
+executable reference, one `evaluate_load` per probe or load, every probe
+cold-started (the same two-engine pattern the path builders use,
+`build_flow_paths`).
 
 Equivalence (tests/test_simulation.py): oblivious modes (min / ecmp /
 valiant / cvaliant) have load-independent splits, so batched probes are
 exact replicas of scalar probes and saturations agree within any `tol`;
 latency-curve entries match per-load `evaluate_load` within 1e-3 relative
-in every mode.  Adaptive modes (UGAL / UGAL_PF) carry intrinsic O(1/iters)
-truncation noise -- near saturation the adaptation gate flattens
-max-utilization to ~0.98 over a wide load range, so the feasibility
-boundary of a *truncated* Frank-Wolfe run keeps drifting with the
-iteration budget (e.g. PF(13) random-perm UGAL_PF saturation moves 0.41 ->
-0.47 between iters=250 and 2000).  Warm-started probes follow a different
-truncation trajectory than cold-started ones, so the engines agree only as
-tightly as the solves are converged: within `tol` = 0.05 at iters >= 3000
-on PF(13) adversarial patterns, and asymptotically as iters grows.
+in every mode (XLA orders the vmapped reductions by batch width, and a
+truncated solve carries that rounding forward; `evaluate_load(x)` is
+`latency_curve([x])[0]` exactly).  Adaptive modes (UGAL / UGAL_PF) carry
+intrinsic O(1/iters) truncation noise -- near saturation the adaptation
+gate flattens max-utilization to ~0.98 over a wide load range, so the
+feasibility boundary of a *truncated* Frank-Wolfe run keeps drifting with
+the iteration budget (e.g. PF(13) random-perm UGAL_PF saturation moves
+0.41 -> 0.47 between iters=250 and 2000).  Warm-started probes follow a
+different truncation trajectory than cold-started ones, so the engines
+agree only as tightly as the solves are converged: within `tol` = 0.05 at
+iters >= 3000 on PF(13) adversarial patterns, and asymptotically as iters
+grows.
 
 Certified engine (``certify=True`` on the public entry points): instead of
 trusting a fixed iteration budget, the solver computes the Frank-Wolfe
@@ -138,25 +148,10 @@ class FluidResult:
 
 @dataclass
 class SaturationResult:
-    """`saturation_throughput(..., return_info=True)` payload.
-
-    `truncation_err` estimates the adaptive-mode Frank-Wolfe truncation
-    noise at the returned saturation load: the L-inf gap between the
-    last-iterate link loads and the running average of the visited iterates'
-    link loads.  Both converge to the Wardrop equilibrium loads, so the gap
-    shrinks as O(1/iters); a gap comparable to the bisection tolerance means
-    `iters` is too low to certify the result (see the module docstring's
-    truncation-noise discussion -- this quantifies the "iters >= 3000" rule
-    of thumb instead of assuming it).  Exactly 0.0 for oblivious modes,
-    whose split is load-independent.
-    """
+    """`saturation_throughput(..., trace=True)` payload on the uncertified
+    engine: the saturation and the per-probe convergence telemetry."""
     saturation: float
-    truncation_err: float
-    # per-probe convergence telemetry when trace=True (None otherwise);
-    # truncation_err is NaN when trace=True was requested without
-    # return_info (the trace subsumes the heuristic, and the extra cold
-    # solve is not free)
-    trace: ConvergenceTrace = None
+    trace: ConvergenceTrace
 
 
 @dataclass
@@ -335,28 +330,31 @@ class _FWPieces(NamedTuple):
     target_of: Callable
     gap_of: Callable
     cert_equilibrate: Callable
-    equilibrate_traced: Callable
 
 
 def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
-               num_links: int, mode: str, barrier: bool = True,
-               dtype=jnp.float32) -> _FWPieces:
+               num_links: int, mode: str, dtype=jnp.float32) -> _FWPieces:
     """Shared Frank-Wolfe building blocks, traced inside each jitted entry.
 
     Returns a `_FWPieces` namedtuple:
 
       init              [F, K] mode-dependent starting split.
-      equilibrate(split0, demand, iters, t0)
-                        `iters` Frank-Wolfe steps from `split0` using step
-                        sizes 2/(t+2) for t = t0, t0+1, ...; identity for
-                        oblivious modes (their split is the fixed point).
+      equilibrate(split0, demand, iters, t0=0.0, trace=False)
+                        -> (split, ys): `iters` Frank-Wolfe steps from
+                        `split0` using step sizes 2/(t+2) for t = t0,
+                        t0+1, ...; identity for oblivious modes (their
+                        split is the fixed point).  With `trace` (static),
+                        `ys` holds the per-step (gap [iters], max_util
+                        [iters], gamma [iters]) samples -- one zero-gap
+                        sample for oblivious modes -- and is `()` without.
       loads(split, demand) -> rho [E]
       cost_of(rho)      -> per-candidate path cost [F, K], routed through
                         `kernels.minplus.path_costs`.
       fw_target(split, rho) -> [F, K] Frank-Wolfe best-response target
                         (adaptive modes only; includes the UGAL_PF gate),
-                        shared by `equilibrate` and the truncation-error
-                        probe so both apply identical per-step math.
+                        the same per-step math `equilibrate` applies, for
+                        the truncation-error probe and UGAL_PF's
+                        certified step.
       target_of(split, rho, cost) -> fw_target with the masked cost
                         precomputed (the certified path needs the raw cost
                         for the gap as well, so it computes cost once).
@@ -366,10 +364,6 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
                         trace_cap)
                         gap-driven conjugate line-search Frank-Wolfe; see
                         below.
-      equilibrate_traced(split0, demand, iters, t0)
-                        `equilibrate` returning per-iteration (gap,
-                        max_util, gamma) scan outputs alongside the split
-                        (see its docstring; trace=True's uncertified path).
 
     `dtype` pins the arithmetic precision of every closure (the uncertified
     engines always pass float32 -- explicitly, so enabling JAX_ENABLE_X64
@@ -385,13 +379,9 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     (`_mxu_link_loads`; its one-hot of the edge ids' high parts is built
     once per call, before the loops), and with "mxu_tiles" one matmul per
     tile of edge ids, over the candidate weights gathered into the tiles'
-    edge order; float64 loads gather as "pad" with either.  The
-    optimization barriers keep XLA from fusing the weight / delay tables
-    into their consuming gathers, which would serialize them; `barrier=False`
-    drops them.  The vmapped batch solvers pass `barrier=False`.  JAX can
-    batch `optimization_barrier`, so this is a choice, not a limit: the
-    batch tests pin the barrier-free program's results, and no TPU
-    measurement yet says the barriers help there.
+    edge order; float64 loads gather as "pad" with either.  Optimization
+    barriers keep XLA from fusing the weight / delay tables into their
+    consuming gathers, in every program, vmapped or not.
 
     Device scopes (`jax.named_scope`, in the op_names of a profile):
     `fluid.loads` (`loads`, every incidence path), `fluid.cost`
@@ -437,9 +427,6 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     # infeasibility certificate's load-conservation budget)
     lmax = jnp.where(valid, (eidx < num_links).sum(-1), 0).max(axis=1)
 
-    def _barrier(x):
-        return jax.lax.optimization_barrier(x) if barrier else x
-
     mxu = (loads_kind in ("mxu", "mxu_tiles")
            and jnp.dtype(dtype) == jnp.float32)
     if mxu:
@@ -456,7 +443,8 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
             return _mxu_link_loads(wm.reshape(-1), hi_onehot, lo_onehot,
                                    num_links)
         if loads_kind in ("pad", "mxu", "mxu_tiles"):
-            w = _barrier(jnp.concatenate([w, jnp.zeros(1, w.dtype)]))
+            w = jax.lax.optimization_barrier(
+                jnp.concatenate([w, jnp.zeros(1, w.dtype)]))
             if mxu:  # the tiles' candidate weights, in edge order
                 return _mxu_link_loads(w[loads_arrays[1]], hi_onehot,
                                        lo_onehot, num_links)
@@ -471,7 +459,8 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
     @jax.named_scope("fluid.cost")
     def cost_of(rho):
         delay = 1.0 + _queue_delay(rho)
-        d = _barrier(jnp.concatenate([delay, jnp.zeros(1, delay.dtype)]))
+        d = jax.lax.optimization_barrier(
+            jnp.concatenate([delay, jnp.zeros(1, delay.dtype)]))
         return path_costs(d, eidx)  # [F,K]
 
     @jax.named_scope("fluid.target")
@@ -502,31 +491,14 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
         per_flow = ((split - target) * c).sum(axis=1)
         return (demand * per_flow).sum()
 
-    def equilibrate(split0, demand, iters: int, t0: float = 0.0):
+    def equilibrate(split0, demand, iters: int, t0: float = 0.0,
+                    trace: bool = False):
+        # with `trace`, the gap is an extra O(F*K) reduction of the cost
+        # the step computes anyway; samples stay on device as scan ys
         if mode not in ("ugal", "ugal_pf"):
-            return split0
-
-        def body(split, t):
-            rho = loads(split, demand)
-            gamma = 2.0 / (t + 2.0)
-            return (1 - gamma) * split + gamma * fw_target(split, rho), None
-
-        split, _ = jax.lax.scan(
-            body, split0, t0 + jnp.arange(iters, dtype=dtype))
-        return split
-
-    def equilibrate_traced(split0, demand, iters: int, t0: float = 0.0):
-        """`equilibrate` with per-iteration telemetry: returns (split,
-        (gap [iters], max_util [iters], gamma [iters])).  Same per-step
-        math (the target is computed from the same masked cost); the gap
-        is an extra O(F*K) reduction of the cost the step computes
-        anyway, so tracing costs a few percent, not a second solve.
-        Samples stay on device (scan ys) -- no host syncs inside jit.
-        Oblivious modes return their fixed point with one zero-gap
-        sample."""
-        if mode not in ("ugal", "ugal_pf"):
-            rho = loads(split0, demand)
-            mu = _max_util(rho, num_links).astype(dtype)
+            if not trace:
+                return split0, ()
+            mu = _max_util(loads(split0, demand), num_links).astype(dtype)
             z = jnp.zeros((1,), dtype)
             return split0, (z, mu[None], z)
 
@@ -534,16 +506,13 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
             rho = loads(split, demand)
             cost = cost_of(rho)
             target = target_of(split, rho, jnp.where(valid, cost, jnp.inf))
-            gap = gap_of(split, target, cost, demand)
             gamma = 2.0 / (t + 2.0)
-            split = (1 - gamma) * split + gamma * target
-            return split, (gap.astype(dtype),
-                           _max_util(rho, num_links).astype(dtype),
-                           gamma.astype(dtype))
+            ys = ((gap_of(split, target, cost, demand).astype(dtype),
+                   _max_util(rho, num_links).astype(dtype),
+                   gamma.astype(dtype)) if trace else ())
+            return (1 - gamma) * split + gamma * target, ys
 
-        split, ys = jax.lax.scan(
-            body, split0, t0 + jnp.arange(iters, dtype=dtype))
-        return split, ys
+        return jax.lax.scan(body, split0, t0 + jnp.arange(iters, dtype=dtype))
 
     # exact line search on gamma in [0, 1]: a short bisection brackets the
     # root of the monotone derivative, then a few false-position (secant
@@ -746,8 +715,7 @@ def _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
 
     init = minvec if mode in ("min", "ugal", "ugal_pf") else uniform
     return _FWPieces(init, equilibrate, loads, cost_of, fw_target,
-                     target_of, gap_of, cert_equilibrate,
-                     equilibrate_traced)
+                     target_of, gap_of, cert_equilibrate)
 
 
 def _mxu_factors(slot_ids, num_links: int):
@@ -814,7 +782,7 @@ def _max_util(rho, num_links: int):
 
 def _metrics(split, rho, cost, valid, hops, demand, offered, num_links: int):
     """In-jit FluidResult fields: (accepted, max_util, mean_latency,
-    mean_hops) -- same formulas `evaluate_load` applies on the host."""
+    mean_hops) of one equilibrium, certified or not."""
     max_util = _max_util(rho, num_links)
     d = demand * offered
     dsum = jnp.maximum(d.sum(), _EPS)
@@ -827,62 +795,26 @@ def _metrics(split, rho, cost, valid, hops, demand, offered, num_links: int):
 
 @functools.partial(jax.jit,
                    static_argnames=("loads_kind", "num_links", "mode",
-                                    "iters"))
-def _solve(eidx, loads_arrays, loads_kind, valid, is_min, first_edge, demand,
-           num_links: int, mode: str, offered: float, iters: int = 250):
-    """Single-load reference solve: (split [F,K], rho [E], cost [F,K])."""
-    fw = _fw_pieces(
-        eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
-        num_links, mode)
-    demand = demand * offered  # [F]
-    split = fw.equilibrate(fw.init, demand, iters)
-    rho = fw.loads(split, demand)
-    return split, rho, fw.cost_of(rho)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("loads_kind", "num_links", "mode",
                                     "iters", "trace"))
 def _solve_batch(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
                  demand, hops, num_links: int, mode: str, offered_vec,
                  iters: int = 250, trace: bool = False):
-    """vmap of the cold-start equilibrium over a vector of offered loads;
-    one compiled call evaluates the whole latency sweep.  With
-    `trace=True` the metrics tuple also carries the per-iteration
-    (gap, max_util, gamma) scan outputs, batched over loads."""
-    fw = _fw_pieces(
-        eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
-        num_links, mode, barrier=False)
-
-    def one(offered):
-        d = demand * offered
-        if trace:
-            split, ys = fw.equilibrate_traced(fw.init, d, iters)
-        else:
-            split = fw.equilibrate(fw.init, d, iters)
-        rho = fw.loads(split, d)
-        m = _metrics(split, rho, fw.cost_of(rho), valid, hops, demand,
-                     offered, num_links)
-        return m + (ys,) if trace else m
-
-    return jax.vmap(one)(offered_vec)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("loads_kind", "num_links", "mode",
-                                    "iters"))
-def _solve_traced(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
-                  demand, num_links: int, mode: str, offered: float,
-                  iters: int = 250):
-    """`_solve` with per-iteration telemetry: (split, rho, cost,
-    (gap, max_util, gamma))."""
+    """vmap of the cold-start equilibrium over a vector of offered loads:
+    (accepted, max_util, mean_latency, mean_hops, ys), each batched over
+    loads, with `ys` the per-step (gap, max_util, gamma) samples when
+    `trace` and `()` without."""
     fw = _fw_pieces(
         eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
         num_links, mode)
-    demand = demand * offered
-    split, ys = fw.equilibrate_traced(fw.init, demand, iters)
-    rho = fw.loads(split, demand)
-    return split, rho, fw.cost_of(rho), ys
+
+    def one(offered):
+        d = demand * offered
+        split, ys = fw.equilibrate(fw.init, d, iters, trace=trace)
+        rho = fw.loads(split, d)
+        return _metrics(split, rho, fw.cost_of(rho), valid, hops, demand,
+                        offered, num_links) + (ys,)
+
+    return jax.vmap(one)(offered_vec)
 
 
 def _probe_schedule(iters: int, probes: int) -> tuple:
@@ -902,10 +834,10 @@ def _probe_schedule(iters: int, probes: int) -> tuple:
 
 @functools.partial(jax.jit,
                    static_argnames=("loads_kind", "num_links", "mode",
-                                    "iters", "probe_schedule"))
+                                    "iters", "probe_schedule", "trace"))
 def _saturation_batch(eidx, loads_arrays, loads_kind, valid, is_min,
                       first_edge, demand, num_links: int, mode: str,
-                      iters: int, probe_schedule: tuple):
+                      iters: int, probe_schedule: tuple, trace: bool = False):
     """In-jit saturation bisection with warm-started Frank-Wolfe probes.
 
     Probe sequence mirrors the scalar engine: a fully converged solve at
@@ -914,63 +846,37 @@ def _saturation_batch(eidx, loads_arrays, loads_kind, valid, is_min,
     the previous probe's split with that entry's step count, resuming the
     step-size schedule at `_WARM_T0` (the probes are unrolled, so each gets
     its own static trip count).
+
+    Returns (sat, traces, brackets).  With `trace`, `traces` is one
+    (gap, max_util, gamma) tuple per probe (probe lengths follow
+    `probe_schedule`, so they stay a Python tuple rather than a stacked
+    array) and `brackets` is [probes + 1, 4] rows (offered, feasible, lo,
+    hi) after each probe; both are `()` without.
     """
     fw = _fw_pieces(
         eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
         num_links, mode)
-    split = fw.equilibrate(fw.init, demand, iters)  # offered = 1.0
-    max1 = _max_util(fw.loads(split, demand), num_links)
-
-    lo = jnp.zeros((), jnp.float32)
-    hi = jnp.ones((), jnp.float32)
-    for probe_iters in probe_schedule:
-        mid = 0.5 * (lo + hi)
-        d = demand * mid
-        split = fw.equilibrate(split, d, probe_iters, t0=_WARM_T0)
-        feasible = _max_util(fw.loads(split, d), num_links) <= 1.0
-        lo = jnp.where(feasible, mid, lo)
-        hi = jnp.where(feasible, hi, mid)
-    return jnp.where(max1 <= 1.0, jnp.ones((), jnp.float32), lo)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("loads_kind", "num_links", "mode",
-                                    "iters", "probe_schedule"))
-def _saturation_batch_traced(eidx, loads_arrays, loads_kind, valid, is_min,
-                             first_edge, demand, num_links: int, mode: str,
-                             iters: int, probe_schedule: tuple):
-    """`_saturation_batch` with per-iteration telemetry on every probe.
-
-    Same probe sequence and per-step math (each probe runs
-    `equilibrate_traced` instead of `equilibrate`); returns
-    (sat, traces, brackets) where `traces` is one (gap, max_util, gamma)
-    tuple per probe (probe lengths follow `probe_schedule`, so they stay
-    a Python tuple rather than a stacked array) and `brackets` is
-    [probes + 1, 4] rows (offered, feasible, lo, hi) after each probe.
-    """
-    fw = _fw_pieces(
-        eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
-        num_links, mode)
-    split, ys0 = fw.equilibrate_traced(fw.init, demand, iters)
-    max1 = _max_util(fw.loads(split, demand), num_links)
+    split, ys = fw.equilibrate(fw.init, demand, iters, trace=trace)
+    max1 = _max_util(fw.loads(split, demand), num_links)  # offered = 1.0
 
     one = jnp.ones((), jnp.float32)
-    lo = jnp.zeros((), jnp.float32)
-    hi = one
-    yss = [ys0]
+    lo, hi = jnp.zeros((), jnp.float32), one
+    yss = [ys]
     brs = [(one, (max1 <= 1.0).astype(jnp.float32), lo, hi)]
-    for probe_iters in probe_schedule:
+    for steps in probe_schedule:
         mid = 0.5 * (lo + hi)
         d = demand * mid
-        split, ys = fw.equilibrate_traced(split, d, probe_iters, t0=_WARM_T0)
+        split, ys = fw.equilibrate(split, d, steps, t0=_WARM_T0,
+                                   trace=trace)
         feasible = _max_util(fw.loads(split, d), num_links) <= 1.0
         lo = jnp.where(feasible, mid, lo)
         hi = jnp.where(feasible, hi, mid)
         yss.append(ys)
         brs.append((mid, feasible.astype(jnp.float32), lo, hi))
     sat = jnp.where(max1 <= 1.0, one, lo)
-    brackets = jnp.stack([jnp.stack(b) for b in brs])
-    return sat, tuple(yss), brackets
+    if not trace:
+        return sat, (), ()
+    return sat, tuple(yss), jnp.stack([jnp.stack(b) for b in brs])
 
 
 @functools.partial(jax.jit,
@@ -1002,36 +908,16 @@ def _truncation_gap(eidx, loads_arrays, loads_kind, valid, is_min, first_edge,
 @functools.partial(jax.jit,
                    static_argnames=("loads_kind", "num_links", "mode",
                                     "max_iters", "dtype", "trace_cap"))
-def _certified_solve(eidx, loads_arrays, loads_kind, valid, is_min,
-                     first_edge, demand, hops, num_links: int, mode: str,
-                     offered, util_tol, max_iters: int, dtype: str,
-                     trace_cap: int = 0):
-    """Single-load certified solve: metrics + (gap, mu_lb, mu_ub, iters,
-    converged, trace)."""
-    dt = jnp.dtype(dtype)
-    fw = _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min,
-                    first_edge, num_links, mode, dtype=dt)
-    dbase = demand.astype(dt)
-    d = dbase * offered
-    split, rho, gap, mu_lb, mu_ub, iters, ok, tr = fw.cert_equilibrate(
-        fw.init, d, max_iters, util_tol, trace_cap=trace_cap)
-    metrics = _metrics(split, rho, fw.cost_of(rho), valid, hops, dbase,
-                       offered, num_links)
-    return metrics + (gap, mu_lb, mu_ub, iters, ok, tr)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("loads_kind", "num_links", "mode",
-                                    "max_iters", "dtype", "trace_cap"))
 def _certified_batch(eidx, loads_arrays, loads_kind, valid, is_min,
                      first_edge, demand, hops, num_links: int, mode: str,
                      offered_vec, util_tol, max_iters: int, dtype: str,
                      trace_cap: int = 0):
-    """vmap of the certified equilibrium over a vector of offered loads
-    (the certify=True latency sweep; barriers off as in `_solve_batch`)."""
+    """vmap of the certified equilibrium over a vector of offered loads:
+    metrics + (gap, mu_lb, mu_ub, iters, converged, trace), each batched
+    over loads."""
     dt = jnp.dtype(dtype)
     fw = _fw_pieces(eidx, loads_arrays, loads_kind, valid, is_min,
-                    first_edge, num_links, mode, barrier=False, dtype=dt)
+                    first_edge, num_links, mode, dtype=dt)
     dbase = demand.astype(dt)
 
     def one(offered):
@@ -1162,7 +1048,7 @@ def _certificate(gap, mu_lb, mu_ub, iters, ok, util_tol, dtype, kind):
 def _cert_trace(mode, kind, tr, brackets=None):
     """Host-side `ConvergenceTrace` from `cert_equilibrate` buffers.
 
-    `tr` is one trace tuple (single solve) or the stacked [P+1, cap]
+    `tr` is one trace tuple (one load) or the stacked [P+1, cap]
     form from `_certified_saturation`; each probe's valid prefix is
     trimmed by its `cnt` and the iteration axis is made cumulative
     across probes.  Runs after the jit returns -- all syncs are here."""
@@ -1191,7 +1077,7 @@ def _cert_trace(mode, kind, tr, brackets=None):
 
 
 def _fw_trace(mode, yss, brackets=None):
-    """Host-side `ConvergenceTrace` from `equilibrate_traced` outputs
+    """Host-side `ConvergenceTrace` from traced `equilibrate` outputs
     (one (gap, max_util, gamma) tuple per probe; stride-1 samples, NaN
     certified bounds -- these runs carry no certificate)."""
     rows = []
@@ -1229,14 +1115,60 @@ def _as_flow_paths(fp) -> FlowPaths:
                     f"{type(fp).__name__}")
 
 
-def _run(fp: FlowPaths, offered: float, iters: int):
-    # device_arrays() is cached on the FlowPaths, so the repeated probes of
-    # saturation bisection / latency sweeps skip the preprocessing and the
-    # host->device copies.
-    eidx, loads_rep, valid, is_min, first_edge, demand, _ = fp.device_arrays()
-    return _solve(eidx, loads_rep[1:], loads_rep[0], valid, is_min,
-                  first_edge, demand, fp.num_links, fp.mode, float(offered),
-                  iters)
+def _equilibria(fp: FlowPaths, loads: list, iters: int, certify: bool,
+                util_tol, dtype, cert_iters, trace: bool, span: str,
+                **attrs) -> list:
+    """One result per offered load in `loads`, from one vmapped program
+    (`_certified_batch` or `_solve_batch`) under the span `span`: the
+    body of `latency_curve`, and of `evaluate_load` as a batch of one."""
+    eidx, loads_rep, valid, is_min, first_edge, demand, hops = \
+        fp.device_arrays()
+    paths = (eidx, loads_rep[1:], loads_rep[0], valid, is_min, first_edge,
+             demand, hops, fp.num_links, fp.mode)
+    rec = get_recorder()
+    if certify:
+        dtype, util_tol, max_iters, kind = _cert_params(
+            fp.mode, util_tol, dtype, iters, cert_iters)
+        trace_cap = (max_iters // _CERT_STRIDE + 2) if trace else 0
+        vec = jnp.asarray(np.asarray(loads, dtype=dtype))
+        with rec.span(span, mode=fp.mode, certify=True, **attrs) as sp:
+            acc, mx, lat, hop, gap, mu_lb, mu_ub, it, ok, tr = sp.sync(
+                _certified_batch(*paths, vec, util_tol, max_iters, dtype,
+                                 trace_cap))
+        if trace:
+            parts = [np.asarray(x) for x in tr]
+            traces = [_cert_trace(fp.mode, kind,
+                                  tuple(p[i] for p in parts))
+                      for i in range(len(loads))]
+        else:
+            traces = [None] * len(loads)
+        return [CertifiedResult(
+                    value=FluidResult(offered=l, accepted=float(a),
+                                      max_util=float(m),
+                                      mean_latency=float(la),
+                                      mean_hops=float(h)),
+                    cert=_certificate(g, lb, ub, i, o, util_tol, dtype, kind),
+                    trace=t)
+                for l, a, m, la, h, g, lb, ub, i, o, t in zip(
+                    loads, np.asarray(acc), np.asarray(mx), np.asarray(lat),
+                    np.asarray(hop), np.asarray(gap), np.asarray(mu_lb),
+                    np.asarray(mu_ub), np.asarray(it), np.asarray(ok),
+                    traces)]
+    vec = jnp.asarray(np.asarray(loads, dtype=np.float32))
+    with rec.span(span, mode=fp.mode, **attrs) as sp:
+        acc, mx, lat, hop, ys = sp.sync(
+            _solve_batch(*paths, vec, iters, trace))
+    if trace:
+        g, mu, gm = (np.asarray(a) for a in ys)
+        traces = [_fw_trace(fp.mode, [(g[i], mu[i], gm[i])])
+                  for i in range(len(loads))]
+    else:
+        traces = [None] * len(loads)
+    return [FluidResult(offered=l, accepted=float(a), max_util=float(m),
+                        mean_latency=float(la), mean_hops=float(h), trace=t)
+            for l, a, m, la, h, t in zip(loads, np.asarray(acc),
+                                         np.asarray(mx), np.asarray(lat),
+                                         np.asarray(hop), traces)]
 
 
 def evaluate_load(fp, offered: float, iters: int = 250,
@@ -1247,65 +1179,23 @@ def evaluate_load(fp, offered: float, iters: int = 250,
     `CertifiedResult` wrapping the FluidResult whose certificate bounds the
     reported utilizations' distance from the exact equilibrium (gap-driven
     line-search Frank-Wolfe instead of a fixed `iters` budget; `cert_iters`
-    caps the certified run, default max(iters, 2000)).
+    caps the certified run, default max(iters, 2000)).  Runs the program
+    of `latency_curve` as a batch of one, so `evaluate_load(fp, x)` equals
+    `latency_curve(fp, [x])[0]`.
 
     With `trace=True` the result additionally carries a
     `repro.obs.trace.ConvergenceTrace` in its `trace` field: per-stride
     (certified) or per-iteration (uncertified) duality gap, step size
     and max utilization, carried out of jit as returned arrays -- the
     compiled solve stays sync-free."""
-    fp = _as_flow_paths(fp)
-    rec = get_recorder()
-    if certify:
-        dtype, util_tol, max_iters, kind = _cert_params(
-            fp.mode, util_tol, dtype, iters, cert_iters)
-        trace_cap = (max_iters // _CERT_STRIDE + 2) if trace else 0
-        eidx, loads_rep, valid, is_min, first_edge, demand, hops = \
-            fp.device_arrays()
-        with rec.span("fluid.evaluate_load", mode=fp.mode, certify=True,
-                      offered=float(offered)) as sp:
-            acc, mu, lat, hop, gap, mu_lb, mu_ub, it, ok, tr = sp.sync(
-                _certified_solve(
-                    eidx, loads_rep[1:], loads_rep[0], valid, is_min,
-                    first_edge, demand, hops, fp.num_links, fp.mode,
-                    float(offered), util_tol, max_iters, dtype, trace_cap))
-        res = FluidResult(offered=float(offered), accepted=float(acc),
-                          max_util=float(mu), mean_latency=float(lat),
-                          mean_hops=float(hop))
-        return CertifiedResult(
-            value=res,
-            cert=_certificate(gap, mu_lb, mu_ub, it, ok, util_tol, dtype,
-                              kind),
-            trace=_cert_trace(fp.mode, kind, tr) if trace else None)
-    with rec.span("fluid.evaluate_load", mode=fp.mode,
-                  offered=float(offered)) as sp:
-        if trace:
-            eidx, loads_rep, valid, is_min, first_edge, demand_dev, _ = \
-                fp.device_arrays()
-            split, rho, cost, ys = sp.sync(_solve_traced(
-                eidx, loads_rep[1:], loads_rep[0], valid, is_min,
-                first_edge, demand_dev, fp.num_links, fp.mode,
-                float(offered), iters))
-        else:
-            split, rho, cost = sp.sync(_run(fp, offered, iters))
-            ys = None
-        split = np.asarray(split)
-        rho = np.asarray(rho)
-        cost = np.asarray(cost)
-    max_util = float(rho.max()) if len(rho) else 0.0
-    demand = fp.pattern.demand * offered
-    wsum = (split * np.where(fp.valid, cost, 0.0)).sum(axis=1)
-    lat = float((demand * wsum).sum() / max(demand.sum(), _EPS))
-    hops = float((demand * (split * fp.hops).sum(axis=1)).sum() / max(demand.sum(), _EPS))
-    accepted = offered * min(1.0, 1.0 / max(max_util, _EPS))
-    return FluidResult(offered=float(offered), accepted=float(accepted),
-                       max_util=max_util, mean_latency=lat, mean_hops=hops,
-                       trace=_fw_trace(fp.mode, [ys]) if trace else None)
+    offered = float(offered)
+    return _equilibria(_as_flow_paths(fp), [offered], iters, certify,
+                       util_tol, dtype, cert_iters, trace,
+                       "fluid.evaluate_load", offered=offered)[0]
 
 
 def saturation_throughput(fp, tol: float = 0.005,
                           iters: int = 250, engine: str = "batched",
-                          probe_iters: int = 0, return_info: bool = False,
                           certify: bool = False, util_tol: float = None,
                           dtype: str = None, cert_iters: int = None,
                           trace: bool = False):
@@ -1314,77 +1204,48 @@ def saturation_throughput(fp, tol: float = 0.005,
     FlowPaths or a sequence of FlowPaths chunks (concatenated on entry).
 
     engine="batched" (default) runs the whole bisection inside one jit with
-    warm-started probes; engine="scalar" is the per-probe reference.
-    `probe_iters` (batched only) fixes every warm probe's Frank-Wolfe step
-    count; 0 picks the default front-loaded schedule (`_probe_schedule`).
-
-    With `return_info=True` the result is a `SaturationResult` that also
-    carries the estimated adaptive-mode truncation error at the returned
-    load (last-iterate vs averaged link loads after a cold `iters`-step
-    solve), so callers can see when `iters` is too low for the bisection
-    tolerance instead of relying on the iters >= 3000 rule of thumb.
+    warm-started probes (`_probe_schedule` budgets); engine="scalar" is the
+    per-probe reference.  `truncation_error(fp, sat, iters)` estimates an
+    uncertified adaptive-mode answer's Frank-Wolfe truncation error.
 
     With `certify=True` the result is a `CertifiedResult`: the bisection
     runs gap-driven probes that early-exit on certified feasibility
     decisions (`_certified_saturation`), `value` is the saturation float
     and `[sat_lo, sat_hi]` the certified bracket.  `util_tol` / `dtype` /
-    `cert_iters` are the certification knobs (`_cert_params`); `certify`
-    supersedes `return_info` (the certificate's gap replaces the
-    truncation-error heuristic) and `probe_iters` (budgets are
-    gap-driven).
+    `cert_iters` are the certification knobs (`_cert_params`).
 
     With `trace=True` (batched or certified engines) the result carries a
     `ConvergenceTrace` covering every bisection probe -- per-probe gap /
     step-size / max-util samples plus a bracket row per probe -- and the
-    uncertified return type becomes `SaturationResult` (its
-    `truncation_err` is NaN unless `return_info` also asked for it).
+    uncertified return type becomes `SaturationResult`.
     """
     fp = _as_flow_paths(fp)
     rec = get_recorder()
+    probes = max(1, int(np.ceil(np.log2(1.0 / tol))))
+    eidx, loads_rep, valid, is_min, first_edge, demand, _ = fp.device_arrays()
+    paths = (eidx, loads_rep[1:], loads_rep[0], valid, is_min, first_edge,
+             demand, fp.num_links, fp.mode)
     if certify:
-        if return_info:
-            raise ValueError("return_info is subsumed by certify=True: the "
-                             "certificate's gap bounds the truncation error")
         dtype, util_tol, max_iters, kind = _cert_params(
             fp.mode, util_tol, dtype, iters, cert_iters)
         trace_cap = (max_iters // _CERT_STRIDE + 2) if trace else 0
-        probes = max(1, int(np.ceil(np.log2(1.0 / tol))))
-        eidx, loads_rep, valid, is_min, first_edge, demand, _ = \
-            fp.device_arrays()
         with rec.span("fluid.saturation_throughput", mode=fp.mode,
                       certify=True, probes=probes) as sp:
             sat, lo_c, hi_c, gap, mu_lb, mu_ub, total_it, ok, trs, brs = \
                 sp.sync(_certified_saturation(
-                    eidx, loads_rep[1:], loads_rep[0], valid, is_min,
-                    first_edge, demand, fp.num_links, fp.mode, util_tol,
-                    max_iters, probes, dtype, trace_cap))
+                    *paths, util_tol, max_iters, probes, dtype, trace_cap))
         return CertifiedResult(
             value=float(sat),
             cert=_certificate(gap, mu_lb, mu_ub, total_it, ok, util_tol,
                               dtype, kind),
             sat_lo=float(lo_c), sat_hi=float(hi_c),
             trace=_cert_trace(fp.mode, kind, trs, brs) if trace else None)
-    tr = None
     if engine == "batched":
-        probes = max(1, int(np.ceil(np.log2(1.0 / tol))))
-        sched = ((probe_iters,) * probes if probe_iters > 0
-                 else _probe_schedule(iters, probes))
-        eidx, loads_rep, valid, is_min, first_edge, demand, _ = \
-            fp.device_arrays()
         with rec.span("fluid.saturation_throughput", mode=fp.mode,
                       probes=probes) as sp:
-            if trace:
-                sat, yss, brs = sp.sync(_saturation_batch_traced(
-                    eidx, loads_rep[1:], loads_rep[0], valid, is_min,
-                    first_edge, demand, fp.num_links, fp.mode, iters,
-                    sched))
-                sat = float(sat)
-                tr = _fw_trace(fp.mode, yss, brs)
-            else:
-                sat = float(sp.sync(_saturation_batch(
-                    eidx, loads_rep[1:], loads_rep[0], valid, is_min,
-                    first_edge, demand, fp.num_links, fp.mode, iters,
-                    sched)))
+            sat, yss, brs = sp.sync(_saturation_batch(
+                *paths, iters, _probe_schedule(iters, probes), trace))
+        sat = float(sat)
     elif engine != "scalar":
         raise ValueError(f"unknown engine {engine!r}")
     elif trace:
@@ -1402,19 +1263,22 @@ def saturation_throughput(fp, tol: float = 0.005,
             else:
                 hi = mid
         sat = lo
-    if not (return_info or trace):
+    if not trace:
         return sat
-    terr = truncation_error(fp, sat, iters) if return_info else float("nan")
-    return SaturationResult(saturation=sat, truncation_err=terr, trace=tr)
+    return SaturationResult(saturation=sat, trace=_fw_trace(fp.mode, yss, brs))
 
 
 def truncation_error(fp, offered: float, iters: int = 250) -> float:
     """Estimated adaptive-mode Frank-Wolfe truncation error at `offered`
-    load: the L-inf gap between last-iterate and averaged link loads after a
-    cold `iters`-step solve (see `SaturationResult`).  0.0 for oblivious
-    modes, whose splits are load-independent fixed points.  Costs one full
-    equilibrium solve -- benchmarks that time the bisection itself should
-    call this outside the timed section."""
+    load: the L-inf gap between the last-iterate link loads and the running
+    average of the visited iterates' link loads after a cold `iters`-step
+    solve.  Both converge to the Wardrop equilibrium loads, so the gap
+    shrinks as O(1/iters); a gap comparable to the bisection tolerance
+    means `iters` is too low for the answer (see the module docstring's
+    truncation-noise discussion).  0.0 for oblivious modes, whose splits
+    are load-independent fixed points.  Costs one full equilibrium solve
+    -- benchmarks that time the bisection itself should call this outside
+    the timed section; `certify=True` bounds the error instead."""
     fp = _as_flow_paths(fp)
     if fp.mode not in ("ugal", "ugal_pf") or not fp.num_links or offered <= 0:
         return 0.0
@@ -1438,64 +1302,11 @@ def latency_curve(fp, loads, iters: int = 250, engine: str = "batched",
     `ConvergenceTrace` (the vmapped solve returns the batched sample
     buffers; they are split per load host-side)."""
     fp = _as_flow_paths(fp)
-    rec = get_recorder()
     loads = [float(l) for l in loads]
-    if certify:
-        dtype, util_tol, max_iters, kind = _cert_params(
-            fp.mode, util_tol, dtype, iters, cert_iters)
-        trace_cap = (max_iters // _CERT_STRIDE + 2) if trace else 0
-        eidx, loads_rep, valid, is_min, first_edge, demand, hops = \
-            fp.device_arrays()
-        vec = jnp.asarray(np.asarray(loads, dtype=dtype))
-        with rec.span("fluid.latency_curve", mode=fp.mode, certify=True,
-                      points=len(loads)) as sp:
-            acc, mx, lat, hop, gap, mu_lb, mu_ub, it, ok, tr = sp.sync(
-                _certified_batch(
-                    eidx, loads_rep[1:], loads_rep[0], valid, is_min,
-                    first_edge, demand, hops, fp.num_links, fp.mode, vec,
-                    util_tol, max_iters, dtype, trace_cap))
-        if trace:
-            parts = [np.asarray(x) for x in tr]
-            traces = [_cert_trace(fp.mode, kind,
-                                  tuple(p[i] for p in parts))
-                      for i in range(len(loads))]
-        else:
-            traces = [None] * len(loads)
-        return [CertifiedResult(
-                    value=FluidResult(offered=l, accepted=float(a),
-                                      max_util=float(m), mean_latency=float(la),
-                                      mean_hops=float(h)),
-                    cert=_certificate(g, lb, ub, i, o, util_tol, dtype, kind),
-                    trace=t)
-                for l, a, m, la, h, g, lb, ub, i, o, t in zip(
-                    loads, np.asarray(acc), np.asarray(mx), np.asarray(lat),
-                    np.asarray(hop), np.asarray(gap), np.asarray(mu_lb),
-                    np.asarray(mu_ub), np.asarray(it), np.asarray(ok),
-                    traces)]
-    if engine == "batched":
-        eidx, loads_rep, valid, is_min, first_edge, demand, hops = \
-            fp.device_arrays()
-        vec = jnp.asarray(np.asarray(loads, dtype=np.float32))
-        with rec.span("fluid.latency_curve", mode=fp.mode,
-                      points=len(loads)) as sp:
-            out = sp.sync(_solve_batch(
-                eidx, loads_rep[1:], loads_rep[0], valid, is_min,
-                first_edge, demand, hops, fp.num_links, fp.mode, vec,
-                iters, trace))
-        if trace:
-            acc, mx, lat, hop, ys = out
-            g, mu, gm = (np.asarray(a) for a in ys)
-            traces = [_fw_trace(fp.mode, [(g[i], mu[i], gm[i])])
-                      for i in range(len(loads))]
-        else:
-            acc, mx, lat, hop = out
-            traces = [None] * len(loads)
-        return [FluidResult(offered=l, accepted=float(a), max_util=float(m),
-                            mean_latency=float(la), mean_hops=float(h),
-                            trace=t)
-                for l, a, m, la, h, t in zip(loads, np.asarray(acc),
-                                             np.asarray(mx), np.asarray(lat),
-                                             np.asarray(hop), traces)]
+    if certify or engine == "batched":
+        return _equilibria(fp, loads, iters, certify, util_tol, dtype,
+                           cert_iters, trace, "fluid.latency_curve",
+                           points=len(loads))
     if engine != "scalar":
         raise ValueError(f"unknown engine {engine!r}")
     return [evaluate_load(fp, l, iters, trace=trace) for l in loads]

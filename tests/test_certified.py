@@ -135,13 +135,21 @@ def test_decide_at_early_exit_on_clear_probes():
 
 def test_latency_curve_certified_matches_single_solves():
     fp = _fp("ugal")
+    el = evaluate_load(fp, 0.1, certify=True, cert_iters=512)
+    # a single solve is the latency curve's program as a batch of one:
+    # every field and the certificate agree exactly
+    one = latency_curve(fp, [0.1], certify=True, cert_iters=512)
+    assert len(one) == 1 and isinstance(one[0], CertifiedResult)
+    assert one[0].value == el.value
+    assert one[0].cert == el.cert
+    # across batch widths XLA orders the vmapped reductions differently,
+    # and an unconverged 512-step solve (bracket ~0.21 wide) carries that
+    # rounding forward: agreement to the 1e-3 the module documents for
+    # latency-curve entries against per-load solves
     lc = latency_curve(fp, [0.1, 0.3], certify=True, cert_iters=512)
     assert len(lc) == 2 and all(isinstance(r, CertifiedResult) for r in lc)
-    el = evaluate_load(fp, 0.1, certify=True, cert_iters=512)
-    # vmapped batch drops the optimization barriers, so agreement is
-    # numerical, not bitwise
     assert lc[0].value.max_util == pytest.approx(el.value.max_util,
-                                                 rel=1e-4)
+                                                 rel=1e-3)
     assert lc[0].cert.iters == el.cert.iters
 
 
@@ -153,8 +161,6 @@ def test_certify_knob_validation():
             evaluate_load(fp, 0.2, certify=True, dtype="float64")
     with pytest.raises(ValueError, match="dtype"):
         evaluate_load(fp, 0.2, certify=True, dtype="bfloat16")
-    with pytest.raises(ValueError, match="return_info"):
-        saturation_throughput(fp, certify=True, return_info=True)
 
 
 def test_certificate_is_exported():
