@@ -6,9 +6,10 @@ q = p^m.  Elements are represented as integers in [0, q): for m == 1 the
 integer itself; for m > 1 the base-p digit packing of the polynomial
 coefficients (little-endian: value = sum_i c_i * p**i).
 
-All operations are exposed as vectorized numpy table lookups so that graph
-construction is O(N^2) array code, and the same tables are shipped to the
-Pallas `gf_crossprod` kernel as int32 arrays.
+All operations are exposed as vectorized numpy table lookups, so that graph
+construction is O(N·q) array code (each vertex's polar line, built from
+cross products), and the same tables are shipped to the Pallas
+`gf_crossprod` kernel as int32 arrays.
 """
 
 from __future__ import annotations

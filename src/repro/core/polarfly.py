@@ -52,6 +52,15 @@ def _enumerate_projective_points(q: int) -> np.ndarray:
     return np.array(pts, dtype=np.int32)
 
 
+def _vertex_ids(vt: np.ndarray, q: int, points: np.ndarray) -> np.ndarray:
+    """Ids of left-normalized `points` [..., 3] among the vertices `vt`
+    (positional code (x·q + y)·q + z; -1 for the zero vector)."""
+    lut = -np.ones(q ** 3, dtype=np.int32)
+    lut[(vt[:, 0] * q + vt[:, 1]) * q + vt[:, 2]] = np.arange(
+        len(vt), dtype=np.int32)
+    return lut[(points[..., 0] * q + points[..., 1]) * q + points[..., 2]]
+
+
 @dataclass
 class PolarFly:
     """ER_q polarity graph with PolarFly vertex taxonomy."""
@@ -107,23 +116,22 @@ class PolarFly:
         """
         vt = self.vertices
         c = self.gf.cross3(vt[:, None, :], vt[None, :, :])  # [N, N, 3]
-        c = self.gf.normalize3(c)
-        # map vectors -> ids via positional encoding
-        q = self.q
-        code = (c[..., 0] * q + c[..., 1]) * q + c[..., 2]
-        lut = -np.ones(q ** 3, dtype=np.int32)
-        vcode = (vt[:, 0] * q + vt[:, 1]) * q + vt[:, 2]
-        lut[vcode] = np.arange(self.n, dtype=np.int32)
-        return lut[code]
+        return _vertex_ids(vt, self.q, self.gf.normalize3(c))
 
 
-def build_polarfly(q: int, chunk: int = 2048) -> PolarFly:
+def build_polarfly(q: int) -> PolarFly:
     """Construct ER_q for any prime power q.
 
+    Each vertex v's neighbours are the q + 1 points of its polar line v^⊥
+    (a quadric's own point dropped): two independent vectors a, b of v^⊥
+    come from cross products of v with the standard basis, and the line's
+    points are normalize(b + t·a) for t in GF(q), plus a.  O(N·q) table
+    lookups, for every prime power.
+
     Spans (``repro.obs``): ``polarfly.points`` (the field and the
-    projective points), ``polarfly.adjacency`` (the chunked all-pairs dot
-    products and the per-row neighbour lists), ``polarfly.classify``
-    (quadrics, V1, V2, the graph and the vertex index)."""
+    projective points), ``polarfly.adjacency`` (the polar lines and the
+    sorted neighbour lists), ``polarfly.classify`` (quadrics, V1, V2, the
+    graph and the vertex index)."""
     if not is_prime_power(q):
         raise ValueError(f"q={q} must be a prime power")
     rec = get_recorder()
@@ -133,20 +141,23 @@ def build_polarfly(q: int, chunk: int = 2048) -> PolarFly:
     n = vt.shape[0]
     assert n == q * q + q + 1
 
-    neighbors = []
-    quadric = np.zeros(n, dtype=bool)
     with rec.span("polarfly.adjacency", n=n):
-        # chunked all-pairs dot products (tables are int32; N^2*3 lookups)
-        for lo in range(0, n, chunk):
-            hi = min(lo + chunk, n)
-            d = gf.dot3(vt[lo:hi, None, :], vt[None, :, :])  # [hi-lo, N]
-            for i in range(lo, hi):
-                row = d[i - lo]
-                nb = np.where(row == 0)[0]
-                if row[i] == 0:
-                    quadric[i] = True
-                    nb = nb[nb != i]
-                neighbors.append(nb.astype(np.int32))
+        quadric = gf.dot3(vt, vt) == 0
+        # v x e_j spans v^⊥ over the two basis vectors e_j off v's leading
+        # coordinate (v has a 1 there, so the pair is independent).
+        crosses = gf.cross3(vt[:, None, :], np.eye(3, dtype=np.int32))
+        lead = np.argmax(vt != 0, axis=1)
+        off_lead = np.array([[1, 2], [0, 2], [0, 1]])[lead]  # [N, 2]
+        rows = np.arange(n)[:, None]
+        a, b = crosses[rows, off_lead].transpose(1, 0, 2)[:, :, None, :]
+        t = np.arange(q, dtype=np.int32)[None, :, None]
+        line = gf.add(b, gf.mul(t, a))  # [N, q, 3]
+        line = np.concatenate([line, a], axis=1)  # [N, q + 1, 3]
+        ids = _vertex_ids(vt, q, gf.normalize3(line))
+        ids[ids == rows] = n  # a quadric lies on its own line
+        ids.sort(axis=1)
+        degree = (q + 1 - quadric).tolist()
+        neighbors = [row[:d] for row, d in zip(ids, degree)]
 
     with rec.span("polarfly.classify"):
         v1 = np.zeros(n, dtype=bool)
@@ -160,6 +171,6 @@ def build_polarfly(q: int, chunk: int = 2048) -> PolarFly:
             params={"q": q, "radix": q + 1},
             labels={"quadric": quadric, "v1": v1, "v2": v2, "vectors": vt},
         )
-        index = {tuple(int(x) for x in vt[i]): i for i in range(n)}
+        index = {tuple(v): i for i, v in enumerate(vt.tolist())}
     return PolarFly(q=q, gf=gf, graph=graph, vertices=vt,
                     quadric_mask=quadric, v1_mask=v1, v2_mask=v2, index=index)

@@ -4,6 +4,7 @@ from math import comb
 import numpy as np
 import pytest
 
+from repro.core.gf import GF
 from repro.core.metrics import diameter_and_aspl, triangle_census
 from repro.core.polarfly import build_polarfly, moore_bound, moore_efficiency
 from repro.core.routing import all_pairs_distances
@@ -91,3 +92,55 @@ def test_paper_intermediate_example():
     s = pf.vertex_id([0, 0, 1])
     d = pf.vertex_id([1, 2, 2])
     assert tuple(pf.vertices[pf.intermediate(s, d)]) == (1, 1, 0)
+
+
+def _er_q_by_definition(q):
+    """ER_q from its definition: all-pairs GF(q) dot products, v ~ w iff
+    v . w == 0, with a quadric's self-loop dropped."""
+    gf = GF(q)
+    vt = np.array([(0, 0, 1)] + [(0, 1, z) for z in range(q)]
+                  + [(1, y, z) for y in range(q) for z in range(q)],
+                  dtype=np.int32)
+    dots = gf.dot3(vt[:, None, :], vt[None, :, :])
+    quadric = np.diag(dots) == 0
+    neighbors = [np.flatnonzero((row == 0) & (np.arange(len(vt)) != i))
+                 .astype(np.int32) for i, row in enumerate(dots)]
+    v1 = (dots[quadric] == 0).any(axis=0) & ~quadric
+    index = {tuple(int(x) for x in v): i for i, v in enumerate(vt)}
+    return vt, neighbors, quadric, v1, ~(quadric | v1), index
+
+
+@pytest.mark.parametrize("q", [2, 3, 4, 5, 7, 8, 9, 11, 13, 16, 25, 27, 31,
+                               32, 49])
+def test_polar_lines_match_the_all_pairs_definition(q):
+    vt, neighbors, quadric, v1, v2, index = _er_q_by_definition(q)
+    pf = build_polarfly(q)
+    np.testing.assert_array_equal(pf.vertices, vt)
+    assert len(pf.graph.neighbors) == len(neighbors)
+    for got, want in zip(pf.graph.neighbors, neighbors):
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(got, want)
+    for got, want in [(pf.quadric_mask, quadric), (pf.v1_mask, v1),
+                      (pf.v2_mask, v2)]:
+        assert got.dtype == bool
+        np.testing.assert_array_equal(got, want)
+    assert pf.index == index
+
+
+@pytest.mark.parametrize("q", [79, 157])
+def test_polar_lines_at_scale(q):
+    """Each row is its vertex's polar line: q + 1 distinct points v^⊥,
+    the vertex itself among them iff it is a quadric (then dropped)."""
+    pf = build_polarfly(q)
+    g, vt, gf = pf.graph, pf.vertices, pf.gf
+    assert g.n == q * q + q + 1
+    g.validate()  # sorted, distinct, no self loops, symmetric
+    indptr, indices = g.csr
+    rows = np.repeat(np.arange(g.n), g.degrees)
+    assert (gf.dot3(vt[rows], vt[indices]) == 0).all()
+    on_own_line = gf.dot3(vt, vt) == 0
+    np.testing.assert_array_equal(pf.quadric_mask, on_own_line)
+    assert on_own_line.sum() == q + 1
+    np.testing.assert_array_equal(g.degrees + on_own_line, q + 1)
+    assert (g.degrees[pf.quadric_mask] == q).all()
+    assert (g.degrees[~pf.quadric_mask] == q + 1).all()
